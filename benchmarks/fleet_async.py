@@ -1,5 +1,10 @@
 """Async fleet benchmark: participation rounds + multi-host scaling.
 
+This is a CPU rehearsal, not a chip measurement: the child always runs
+on the host CPU with 4 fake hosts (``JAX_PLATFORMS=cpu``), even on a
+machine with a TPU, and its row records the platform it ran on.  Its
+wall-clock numbers are CPU times.
+
 Like ``serving_sharded``, the measurement needs a multi-device jax
 runtime (4 fake hosts), so ``fleet_async_bench`` re-execs THIS module
 as a child under ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
@@ -168,7 +173,10 @@ def _child_main(n_devices: int = 16, rounds: int = 3,
     print(f"host scaling: {b1} B resident at 1 host vs {b4} B at "
           f"{_N_HOSTS} hosts ({scaling}x)")
 
+    dev = jax.devices()[0]
     row = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "n_devices": n_devices,
         "rounds": rounds,
         "steps_per_round": steps_per_round,

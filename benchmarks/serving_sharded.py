@@ -1,6 +1,11 @@
 """Sharded serving benchmark: decode-mesh engine vs single device, and
 the EP-A2A overlap win.
 
+This is a CPU rehearsal, not a chip measurement: the child always runs
+on the host CPU with 8 fake devices (``JAX_PLATFORMS=cpu``), even on a
+machine with a TPU, and its row records the platform it ran on.  Its
+tok/s are CPU wall-clock numbers.
+
 The measurement needs a multi-device jax runtime, but the bench runner
 process has usually initialised jax single-device already (XLA_FLAGS
 cannot be applied after backend init) — so ``serving_sharded_bench``
@@ -137,8 +142,11 @@ def _child_main(n_requests: int = 8, n_slots: int = 4, seg_len: int = 4,
     assert outputs["sharded_overlap"] == outputs["single"], \
         "overlapped engine diverged from single-device"
 
+    dev = jax.devices()[0]
     row = {
         "arch": cfg.name,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "mesh": {"data": mesh.shape["data"], "model": mesh.shape["model"]},
         "traffic": {"n_requests": n_requests, "seed": seed,
                     "total_tokens": total_tokens},

@@ -53,8 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.federated import FederatedCorpus
-from repro.federated.device import (DeviceSpec, _device_init,
-                                    _fleet_round_fn, _pad_lanes,
+from repro.federated.device import (DeviceSpec, _fleet_round_fn,
+                                    _init_bucket, _pad_lanes,
                                     _shard_bucket, _stack_trees, _upload,
                                     device_upload_bytes, fleet_buckets,
                                     model_param_bytes, sample_traffic)
@@ -94,12 +94,8 @@ def train_fleet_async(fleet: Sequence[DeviceSpec], corpus: FederatedCorpus,
     buckets = fleet_buckets(fleet)
     state: Dict = {}
     for cfg, specs in buckets.items():
-        inits = [_device_init(s, seed, state_policy) for s in specs]
-        state[cfg] = {
-            "specs": specs,
-            "params": _stack_trees([p for p, _ in inits]),
-            "opt": _stack_trees([o for _, o in inits]),
-        }
+        params, opt = _init_bucket(specs, seed, state_policy)
+        state[cfg] = {"specs": specs, "params": params, "opt": opt}
     local_step = {s.device_id: 0 for s in fleet}
     losses: Dict[int, List[float]] = {s.device_id: [] for s in fleet}
 

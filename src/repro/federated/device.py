@@ -204,16 +204,22 @@ def _device_step_fn(cfg: ModelConfig):
     return jax.jit(_step_core(cfg))
 
 
-def _device_init(spec: DeviceSpec, seed: int, state_policy: str = ""):
-    params = M.init_params(
+def _device_params(spec: DeviceSpec, seed: int):
+    return M.init_params(
         jax.random.PRNGKey(seed * 100003 + spec.device_id), spec.cfg)
+
+
+def _device_init(spec: DeviceSpec, seed: int, state_policy: str = ""):
+    params = _device_params(spec, seed)
     return params, adamw_init(params, policy=state_policy)
 
 
 def _upload(spec: DeviceSpec, corpus: FederatedCorpus, params,
             losses) -> Dict:
     return {
-        "params": params,
+        # an upload goes to the server, which keeps it in host memory:
+        # the accelerator's memory is for the training that follows
+        "params": jax.device_get(params),
         "embedding": corpus.device_embedding(spec.device_id),
         "losses": [float(x) for x in np.asarray(losses)],
         "upload_bytes": device_upload_bytes(spec.comm_cfg),
@@ -265,6 +271,18 @@ def fleet_buckets(fleet: Sequence[DeviceSpec]
 
 def _stack_trees(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+
+
+def _init_bucket(specs: Sequence[DeviceSpec], seed: int,
+                 state_policy: str = ""):
+    """(params, opt) of one arch bucket, stacked along a device axis.
+
+    Equal to stacking each device's ``_device_init``, but the optimizer
+    state is built on the stacked params: a per-device copy of the
+    bucket's whole training state never sits beside the stacked one."""
+    params = _stack_trees([_device_params(s, seed) for s in specs])
+    return params, jax.vmap(
+        functools.partial(adamw_init, policy=state_policy))(params)
 
 
 def _pad_lanes(tree, n_pad: int):
@@ -321,9 +339,7 @@ def train_fleet(fleet: Sequence[DeviceSpec], corpus: FederatedCorpus, *,
     uploads: Dict[int, Dict] = {}
     warmup = max(steps // 20, 1)
     for cfg, specs in fleet_buckets(fleet).items():
-        inits = [_device_init(s, seed, state_policy) for s in specs]
-        params = _stack_trees([p for p, _ in inits])
-        opt = _stack_trees([o for _, o in inits])
+        params, opt = _init_bucket(specs, seed, state_policy)
         batches = _stack_trees(
             [corpus.device_batches(s.device_id, steps, batch, seq_len)
              for s in specs])
@@ -333,7 +349,8 @@ def train_fleet(fleet: Sequence[DeviceSpec], corpus: FederatedCorpus, *,
                                     for t in (params, opt, batches))
             params, opt, batches = _shard_bucket(mesh, params, opt, batches)
         epoch = _fleet_epoch_fn(cfg, steps, lr, warmup)
-        params, _, losses = epoch(params, opt, batches)
+        params, opt, losses = epoch(params, opt, batches)
+        del opt     # free this bucket's optimizer state before the next one
         losses = np.asarray(losses)          # one host sync per bucket
         for i, spec in enumerate(specs):
             uploads[spec.device_id] = _upload(

@@ -255,7 +255,10 @@ class DeepFusionServer:
         hist = [float(x) for x in np.asarray(losses)]
         self.log(f"Phase II: proxy c{proxy_item['cluster']} distilled "
                  f"loss {hist[0]:.3f}->{hist[-1]:.3f}")
-        return trainable["student"], hist
+        # the finished base model waits in host memory for the Phase III
+        # merge: K vocab-wide bases would not fit beside the next
+        # proxy's training state on one accelerator
+        return jax.device_get(trainable["student"]), hist
 
     # ------------------------------------------------------------------
     # Phase III

@@ -17,7 +17,9 @@ Finalisation (last vocab tile):
 
 Grid: (nT, nV); vocab tiles are the sequential innermost dimension.
 Tiles: hs (Bt, Ds), ws (Ds, Bv), ht (Bt, Dt), wt (Dt, Bv) — two MXU
-matmuls per step; VMEM ~ (Bt+Bv)·D·4B, MXU-aligned at Bt=Bv=128.
+matmuls per step with f32 accumulation; tiles stay in their storage
+dtype, so VMEM ~ 2·(Bt+Bv)·D·itemsize with double buffering.  Labels,
+outputs and per-row statistics are (Bt, 1) columns.
 
 The backward pass is a vocab-blocked jnp scan (see ops.py custom_vjp) —
 mathematically the same streaming pattern, left to XLA.
@@ -40,12 +42,24 @@ def _softcap(z, cap):
     return z
 
 
+def _dot_f32(a, b):
+    """MXU matmul with f32 accumulation, without widening whole weight
+    tiles to f32 in VMEM first (a (D, Bv) f32 copy of each vocab tile
+    would double the kernel's scoped-VMEM footprint)."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot(a.astype(dt), b.astype(dt),
+                       preferred_element_type=jnp.float32)
+
+
 def _kd_kernel(hs_ref, ws_ref, ht_ref, wt_ref, lab_ref,
                ce_ref, kl_ref, cor_ref,
                ms_scr, ls_scr, gold_scr, bmax_scr, barg_scr,
                mst_scr, lst_scr, mtt_scr, ltt_scr, u_scr, w_scr, *,
                tau: float, softcap_s: float, softcap_t: float,
                block_v: int, vocab: int, with_teacher: bool):
+    # per-row statistics, labels and outputs are (Bt, 1) columns: 2-D
+    # blocks whose lane dim spans the whole (1-wide) array, a layout
+    # Mosaic and XLA agree on (1-D (Bt,) blocks do not)
     vi = pl.program_id(1)
     nv = pl.num_programs(1)
 
@@ -63,9 +77,13 @@ def _kd_kernel(hs_ref, ws_ref, ht_ref, wt_ref, lab_ref,
         u_scr[...] = jnp.zeros_like(u_scr)
         w_scr[...] = jnp.zeros_like(w_scr)
 
-    hs = hs_ref[...].astype(jnp.float32)              # (Bt, Ds)
-    ws = ws_ref[...].astype(jnp.float32)              # (Ds, Bv)
-    zs = _softcap(jax.lax.dot(hs, ws), softcap_s)     # (Bt, Bv)
+    def row_max(z):
+        return jnp.max(z, axis=-1, keepdims=True)
+
+    def row_sum(z):
+        return jnp.sum(z, axis=-1, keepdims=True)
+
+    zs = _softcap(_dot_f32(hs_ref[...], ws_ref[...]), softcap_s)  # (Bt, Bv)
     v0 = vi * block_v
     vids = v0 + jax.lax.broadcasted_iota(jnp.int32, zs.shape, 1)
     valid = vids < vocab
@@ -73,42 +91,41 @@ def _kd_kernel(hs_ref, ws_ref, ht_ref, wt_ref, lab_ref,
 
     # ---- student raw-logit statistics (CE + accuracy) -------------------
     m_prev = ms_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(zs, axis=-1))
+    m_new = jnp.maximum(m_prev, row_max(zs))
     ls_scr[...] = ls_scr[...] * jnp.exp(m_prev - m_new) + \
-        jnp.sum(jnp.where(valid, jnp.exp(zs - m_new[:, None]), 0.0), axis=-1)
+        row_sum(jnp.where(valid, jnp.exp(zs - m_new), 0.0))
     ms_scr[...] = m_new
-    lab = lab_ref[...]
-    hit = vids == lab[:, None]
-    gold_scr[...] += jnp.sum(jnp.where(hit, zs, 0.0), axis=-1)
-    blk_max = jnp.max(zs, axis=-1)
-    blk_arg = v0 + jnp.argmax(zs, axis=-1).astype(jnp.int32)
+    hit = vids == lab_ref[...]
+    gold_scr[...] += row_sum(jnp.where(hit, zs, 0.0))
+    blk_max = row_max(zs)
+    # first column attaining the block max (argmax's tie rule)
+    blk_arg = jnp.min(jnp.where(zs == blk_max, vids, jnp.iinfo(jnp.int32).max),
+                      axis=-1, keepdims=True)
     better = blk_max > bmax_scr[...]
     barg_scr[...] = jnp.where(better, blk_arg, barg_scr[...])
     bmax_scr[...] = jnp.where(better, blk_max, bmax_scr[...])
 
     if with_teacher:
-        ht = ht_ref[...].astype(jnp.float32)
-        wt = wt_ref[...].astype(jnp.float32)
-        zt = _softcap(jax.lax.dot(ht, wt), softcap_t)
+        zt = _softcap(_dot_f32(ht_ref[...], wt_ref[...]), softcap_t)
         zt = jnp.where(valid, zt, NEG_INF)
         zs_t = zs / tau
         zt_t = zt / tau
         # student temperature-side lse
         m_prev = mst_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(zs_t, axis=-1))
-        lst_scr[...] = lst_scr[...] * jnp.exp(m_prev - m_new) + jnp.sum(
-            jnp.where(valid, jnp.exp(zs_t - m_new[:, None]), 0.0), axis=-1)
+        m_new = jnp.maximum(m_prev, row_max(zs_t))
+        lst_scr[...] = lst_scr[...] * jnp.exp(m_prev - m_new) + row_sum(
+            jnp.where(valid, jnp.exp(zs_t - m_new), 0.0))
         mst_scr[...] = m_new
         # teacher-side online stats (lse + U + cross W)
         m_prev = mtt_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(zt_t, axis=-1))
+        m_new = jnp.maximum(m_prev, row_max(zt_t))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(zt_t - m_new[:, None]), 0.0)
-        ltt_scr[...] = ltt_scr[...] * corr + jnp.sum(p, axis=-1)
-        u_scr[...] = u_scr[...] * corr + jnp.sum(
-            p * jnp.where(valid, zt_t, 0.0), axis=-1)
-        w_scr[...] = w_scr[...] * corr + jnp.sum(
-            p * jnp.where(valid, zs_t, 0.0), axis=-1)
+        p = jnp.where(valid, jnp.exp(zt_t - m_new), 0.0)
+        ltt_scr[...] = ltt_scr[...] * corr + row_sum(p)
+        u_scr[...] = u_scr[...] * corr + row_sum(
+            p * jnp.where(valid, zt_t, 0.0))
+        w_scr[...] = w_scr[...] * corr + row_sum(
+            p * jnp.where(valid, zs_t, 0.0))
         mtt_scr[...] = m_new
 
     @pl.when(vi == nv - 1)
@@ -157,9 +174,10 @@ def kd_loss_fwd(hs, ws, ht, wt, labels, *, tau: float, softcap_s: float,
     kern = functools.partial(
         _kd_kernel, tau=tau, softcap_s=softcap_s, softcap_t=softcap_t,
         block_v=bv, vocab=V, with_teacher=with_teacher)
-    scr = [pltpu.VMEM((bt,), jnp.float32) for _ in range(4)]
-    scr += [pltpu.VMEM((bt,), jnp.int32)]
-    scr += [pltpu.VMEM((bt,), jnp.float32) for _ in range(6)]
+    col = (bt, 1)
+    scr = [pltpu.VMEM(col, jnp.float32) for _ in range(4)]
+    scr += [pltpu.VMEM(col, jnp.int32)]
+    scr += [pltpu.VMEM(col, jnp.float32) for _ in range(6)]
     ce, kl, cor = pl.pallas_call(
         kern,
         grid=(nt, nv),
@@ -168,15 +186,11 @@ def kd_loss_fwd(hs, ws, ht, wt, labels, *, tau: float, softcap_s: float,
             pl.BlockSpec((Ds, bv), lambda t, v: (0, v)),
             pl.BlockSpec((bt, Dt), lambda t, v: (t, 0)),
             pl.BlockSpec((Dt, bv), lambda t, v: (0, v)),
-            pl.BlockSpec((bt,), lambda t, v: (t,)),
+            pl.BlockSpec(col, lambda t, v: (t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bt,), lambda t, v: (t,)),
-            pl.BlockSpec((bt,), lambda t, v: (t,)),
-            pl.BlockSpec((bt,), lambda t, v: (t,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((nt * bt,), jnp.float32)] * 3,
+        out_specs=[pl.BlockSpec(col, lambda t, v: (t, 0))] * 3,
+        out_shape=[jax.ShapeDtypeStruct((nt * bt, 1), jnp.float32)] * 3,
         scratch_shapes=scr,
         interpret=interpret,
-    )(hs, ws, ht, wt, labels)
-    return ce[:T], kl[:T], cor[:T]
+    )(hs, ws, ht, wt, labels.astype(jnp.int32)[:, None])
+    return ce[:T, 0], kl[:T, 0], cor[:T, 0]

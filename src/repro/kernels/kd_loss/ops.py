@@ -32,17 +32,16 @@ def _softcap_and_grad(z, cap):
     return t * cap, 1.0 - t * t
 
 
-def _lse_stats(h, *, softcap, blocks, vocab, block_v, tau: float = 1.0):
-    """Streaming logsumexp over vocab blocks (pad-masked).  Returns (m, l)."""
+def _lse_stats(h, w, *, softcap, vocab, block_v, tau: float = 1.0):
+    """Streaming logsumexp over vocab blocks of ``w`` (D, Vp), pad-masked.
+    Returns (m, l)."""
     T = h.shape[0]
     m = jnp.full((T,), -1e30, jnp.float32)
     l = jnp.zeros((T,), jnp.float32)
-    nv = blocks.shape[0]
 
-    def body(carry, inp):
+    def body(carry, vi):
         m, l = carry
-        wb, vi = inp
-        z, _ = _softcap_and_grad(h @ wb, softcap)
+        z, _ = _softcap_and_grad(h @ _vocab_block(w, vi, block_v), softcap)
         z = z / tau
         vids = vi * block_v + jnp.arange(z.shape[1])
         z = jnp.where((vids < vocab)[None, :], z, -1e30)
@@ -50,17 +49,27 @@ def _lse_stats(h, *, softcap, blocks, vocab, block_v, tau: float = 1.0):
         l = l * jnp.exp(m - m_new) + jnp.sum(jnp.exp(z - m_new[:, None]), -1)
         return (m_new, l), 0
 
-    (m, l), _ = jax.lax.scan(body, (m, l), (blocks, jnp.arange(nv)))
+    (m, l), _ = jax.lax.scan(body, (m, l), jnp.arange(w.shape[1] // block_v))
     return m, l
 
 
-def _split_vocab(w, block_v):
-    D, V = w.shape
-    pad = (-V) % block_v
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-    nv = w.shape[1] // block_v
-    return w.T.reshape(nv, block_v, D).transpose(0, 2, 1), pad  # (nv, D, bv)
+def _pad_vocab(w, block_v):
+    """(D, V) -> (D, Vp) with Vp a multiple of ``block_v``."""
+    pad = (-w.shape[1]) % block_v
+    return jnp.pad(w, ((0, 0), (0, pad))) if pad else w
+
+
+def _vocab_block(w, vi, block_v):
+    """Vocab tile ``vi`` of a padded (D, Vp) head, widened to f32 one tile
+    at a time so the whole head never exists in f32."""
+    return jax.lax.dynamic_slice_in_dim(w, vi * block_v, block_v,
+                                        axis=1).astype(jnp.float32)
+
+
+def _put_block(dw, dwb, vi, block_v):
+    """Write one vocab tile of the head gradient into the (D, Vp) carry."""
+    return jax.lax.dynamic_update_slice_in_dim(dw, dwb.astype(dw.dtype),
+                                               vi * block_v, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -84,14 +93,13 @@ def _ce_bwd(softcap, block_v, interpret, res, cots):
     hs, ws, labels = res
     dce = cots[0]  # (T,)
     hsf = hs.astype(jnp.float32)
-    blocks, pad = _split_vocab(ws.astype(jnp.float32), block_v)
     V = ws.shape[1]
-    m, l = _lse_stats(hsf, softcap=softcap, blocks=blocks, vocab=V,
-                      block_v=block_v)
+    wp = _pad_vocab(ws, block_v)
+    m, l = _lse_stats(hsf, wp, softcap=softcap, vocab=V, block_v=block_v)
 
-    def body(carry, inp):
-        dhs, dws_blocks_i = carry
-        wb, vi = inp
+    def body(carry, vi):
+        dhs, dws = carry
+        wb = _vocab_block(wp, vi, block_v)
         z_raw = hsf @ wb
         z, dz_cap = _softcap_and_grad(z_raw, softcap)
         p = jnp.exp(z - m[:, None]) / l[:, None]
@@ -101,14 +109,12 @@ def _ce_bwd(softcap, block_v, interpret, res, cots):
         valid = (vids < V).astype(jnp.float32)[None, :]
         dz = (p - onehot) * dce[:, None] * dz_cap * valid
         dhs = dhs + dz @ wb.T
-        dwb = hsf.T @ dz
-        return (dhs, 0), dwb
+        return (dhs, _put_block(dws, hsf.T @ dz, vi, block_v)), 0
 
-    nv = blocks.shape[0]
-    (dhs, _), dws_blocks = jax.lax.scan(
-        body, (jnp.zeros_like(hsf), 0), (blocks, jnp.arange(nv)))
-    dws = dws_blocks.transpose(1, 0, 2).reshape(hs.shape[1], -1)[:, :V]
-    return dhs.astype(hs.dtype), dws.astype(ws.dtype), None
+    (dhs, dws), _ = jax.lax.scan(
+        body, (jnp.zeros_like(hsf), jnp.zeros_like(wp)),
+        jnp.arange(wp.shape[1] // block_v))
+    return dhs.astype(hs.dtype), dws[:, :V], None
 
 
 _ce.defvjp(_ce_fwd, _ce_bwd)
@@ -148,21 +154,22 @@ def _ce_kl_bwd(tau, softcap_s, softcap_t, block_v, interpret, res, cots):
     hs, ws, ht, wt, labels = res
     dce, dkl = cots[0], cots[1]
     hsf, htf = hs.astype(jnp.float32), ht.astype(jnp.float32)
-    sblocks, _ = _split_vocab(ws.astype(jnp.float32), block_v)
-    tblocks, _ = _split_vocab(wt.astype(jnp.float32), block_v)
+    wsp, wtp = _pad_vocab(ws, block_v), _pad_vocab(wt, block_v)
     V = ws.shape[1]
 
     # pass 1: statistics (pad-masked)
-    m_s, l_s = _lse_stats(hsf, softcap=softcap_s, blocks=sblocks, vocab=V,
+    m_s, l_s = _lse_stats(hsf, wsp, softcap=softcap_s, vocab=V,
                           block_v=block_v)
-    m_st, l_st = _lse_stats(hsf, softcap=softcap_s, blocks=sblocks, vocab=V,
+    m_st, l_st = _lse_stats(hsf, wsp, softcap=softcap_s, vocab=V,
                             block_v=block_v, tau=tau)
-    m_tt, l_tt = _lse_stats(htf, softcap=softcap_t, blocks=tblocks, vocab=V,
+    m_tt, l_tt = _lse_stats(htf, wtp, softcap=softcap_t, vocab=V,
                             block_v=block_v, tau=tau)
 
     # pass 2: gradient tiles
-    def body(dhs, inp):
-        wsb, wtb, vi = inp
+    def body(carry, vi):
+        dhs, dws = carry
+        wsb = _vocab_block(wsp, vi, block_v)
+        wtb = _vocab_block(wtp, vi, block_v)
         zs_raw = hsf @ wsb
         zs, dcap_s = _softcap_and_grad(zs_raw, softcap_s)
         zt, _ = _softcap_and_grad(htf @ wtb, softcap_t)
@@ -176,15 +183,13 @@ def _ce_kl_bwd(tau, softcap_s, softcap_t, block_v, interpret, res, cots):
         dz = ((p_raw - onehot) * dce[:, None]
               + tau * (p_st - p_tt) * dkl[:, None]) * dcap_s * valid
         dhs = dhs + dz @ wsb.T
-        dwb = hsf.T @ dz
-        return dhs, dwb
+        return (dhs, _put_block(dws, hsf.T @ dz, vi, block_v)), 0
 
-    nv = sblocks.shape[0]
-    dhs, dws_blocks = jax.lax.scan(
-        body, jnp.zeros_like(hsf), (sblocks, tblocks, jnp.arange(nv)))
-    dws = dws_blocks.transpose(1, 0, 2).reshape(hs.shape[1], -1)[:, :V]
+    (dhs, dws), _ = jax.lax.scan(
+        body, (jnp.zeros_like(hsf), jnp.zeros_like(wsp)),
+        jnp.arange(wsp.shape[1] // block_v))
     # teacher is frozen (Eq. 10): zero cotangents
-    return (dhs.astype(hs.dtype), dws.astype(ws.dtype),
+    return (dhs.astype(hs.dtype), dws[:, :V],
             jnp.zeros_like(ht), jnp.zeros_like(wt), None)
 
 

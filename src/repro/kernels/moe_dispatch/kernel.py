@@ -9,9 +9,9 @@ One primitive covers all four movements of a routed MoE layer:
 * combine   = gather slots, scatter-add into tokens (scale = w * keep)
 * their backwards are the same primitive with src/dst swapped.
 
-Row indices and scales ride in SMEM via scalar prefetch; src and the
-f32 accumulator live whole in VMEM.  That bounds the kernel to movements
-whose src + out fit the VMEM budget — ``ops.token_dispatch`` /
+Row indices and scales ride in SMEM via scalar prefetch; src (widened
+to f32) and the f32 accumulator live whole in VMEM.  That bounds the
+kernel to movements whose src + out fit the VMEM budget — ``ops.token_dispatch`` /
 ``token_combine`` check ``fits_vmem`` and fall back to the XLA
 scatter-add implementation for larger buffers (e.g. the a2a send buffer
 at production ep_size; a row-tiled multi-pass variant is a listed
@@ -43,9 +43,7 @@ def _gsa_kernel(src_rows_ref, dst_rows_ref, scale_ref, src_ref, out_ref):
         s = src_rows_ref[r]
         d = dst_rows_ref[r]
         c = scale_ref[r]
-        row = pl.load(src_ref, (pl.ds(s, 1), slice(None))).astype(jnp.float32)
-        cur = pl.load(out_ref, (pl.ds(d, 1), slice(None)))
-        pl.store(out_ref, (pl.ds(d, 1), slice(None)), cur + c * row)
+        out_ref[pl.ds(d, 1), :] += c * src_ref[pl.ds(s, 1), :]
         return 0
 
     jax.lax.fori_loop(0, src_rows_ref.shape[0], body, 0)
@@ -55,9 +53,12 @@ def gather_scatter_add_rows(src, src_rows, dst_rows, scale, n_out: int, *,
                             interpret: bool = False):
     """src: (Ns, D); src_rows/dst_rows: (R,) int32; scale: (R,) -> (n_out, D).
 
-    Accumulates in f32, returns ``src.dtype``.  Out-of-capacity rows are
-    expressed as ``scale == 0`` (the row still moves, adds nothing), so
-    index arrays never need masking beyond clamping into range.
+    Accumulates in f32, returns ``src.dtype``.  The source is widened to
+    f32 before the call: the kernel moves one dynamic row at a time, and
+    a single row of a packed 16-bit array is not a whole sublane tile.
+    Out-of-capacity rows are expressed as ``scale == 0`` (the row still
+    moves, adds nothing), so index arrays never need masking beyond
+    clamping into range.
     """
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -71,5 +72,5 @@ def gather_scatter_add_rows(src, src_rows, dst_rows, scale, n_out: int, *,
         out_shape=jax.ShapeDtypeStruct((n_out, src.shape[1]), jnp.float32),
         interpret=interpret,
     )(src_rows.astype(jnp.int32), dst_rows.astype(jnp.int32),
-      scale.astype(jnp.float32), src)
+      scale.astype(jnp.float32), src.astype(jnp.float32))
     return out.astype(src.dtype)

@@ -17,17 +17,46 @@ implementations share the same index/mask computation, so the three
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.moe_dispatch.kernel import (fits_vmem,
+from repro.kernels.moe_dispatch.kernel import (VMEM_BUDGET_BYTES, fits_vmem,
                                                gather_scatter_add_rows)
 
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+_DISPATCH_PATH_LOGGED: set = set()
+
+
+def dispatch_path(n_src: int, n_out: int, d: int, *, use_kernel: bool,
+                  interpret: bool) -> str:
+    """Which implementation moves a dispatch/combine's rows: ``"pallas"``
+    (the fused row kernel) or ``"xla"`` (the ``.at[].add`` scatter).
+
+    The kernel keeps its source and f32 accumulator whole in VMEM, so
+    the choice follows the shape.  It is logged once per distinct
+    reason, like ``layers.paged_read_path``, so a run on the chip shows
+    which path every MoE call took."""
+    if not use_kernel:
+        path, why = "xla", "use_pallas=False"
+    elif interpret:
+        path, why = "pallas", "interpret mode"
+    elif fits_vmem(n_src, n_out, d):
+        path, why = "pallas", f"{n_src}+{n_out} rows x {d} fit VMEM"
+    else:
+        path, why = "xla", (f"{n_src}+{n_out} rows x {d} exceed the "
+                            f"{VMEM_BUDGET_BYTES} B VMEM budget")
+    if (path, why) not in _DISPATCH_PATH_LOGGED:
+        _DISPATCH_PATH_LOGGED.add((path, why))
+        logging.getLogger(__name__).info("moe_dispatch path: %s (%s)",
+                                         path, why)
+    return path
 
 
 def capacity_positions(flat_e, cap: int, valid=None):
@@ -93,8 +122,8 @@ def token_dispatch(xt, flat_tok, slot, keep, n_slots: int, *,
         interpret = _on_cpu()
     scale = keep.astype(jnp.float32)
     dst = jnp.where(keep, slot, 0).astype(jnp.int32)
-    if use_kernel and (interpret
-                       or fits_vmem(xt.shape[0], n_slots, xt.shape[1])):
+    if dispatch_path(xt.shape[0], n_slots, xt.shape[1], use_kernel=use_kernel,
+                     interpret=interpret) == "pallas":
         return _gsa(xt, scale, flat_tok.astype(jnp.int32), dst, n_slots,
                     interpret)
     return jnp.zeros((n_slots, xt.shape[1]), xt.dtype).at[dst].add(
@@ -110,8 +139,8 @@ def token_combine(y2d, flat_tok, slot, keep, weights, n_tokens: int, *,
         interpret = _on_cpu()
     scale = jnp.where(keep, weights, 0.0)
     srcr = jnp.where(keep, slot, 0).astype(jnp.int32)
-    if use_kernel and (interpret
-                       or fits_vmem(y2d.shape[0], n_tokens, y2d.shape[1])):
+    if dispatch_path(y2d.shape[0], n_tokens, y2d.shape[1],
+                     use_kernel=use_kernel, interpret=interpret) == "pallas":
         return _gsa(y2d, scale, srcr, flat_tok.astype(jnp.int32), n_tokens,
                     interpret)
     gathered = jnp.where(keep[:, None], y2d[srcr], 0.0)

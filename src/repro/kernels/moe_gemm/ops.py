@@ -50,23 +50,24 @@ def _grouped_ffn_fwd(x, wg, wu, wo, act, blocks, interpret):
 
 
 def _grouped_ffn_bwd(act, blocks, interpret, res, dy):
+    # the grouped-matmul kernel widens each tile to f32 and accumulates
+    # in f32, so operands keep their storage dtype here (no whole-tensor
+    # f32 copies of the expert weights) and the weight gradients are
+    # written straight in the weights' dtype
     x, wg, wu, wo = res
-    gmm = functools.partial(grouped_matmul, interpret=interpret)
+    gmm = functools.partial(grouped_matmul, interpret=interpret,
+                            out_dtype=jnp.float32)
     tr = lambda a: jnp.swapaxes(a, -1, -2)
-    xf = x.astype(jnp.float32)
-    dyf = dy.astype(jnp.float32)
-    g = gmm(xf, wg.astype(jnp.float32))          # (E, C, F)
-    u = gmm(xf, wu.astype(jnp.float32))
+    g = gmm(x, wg)                               # (E, C, F)
+    u = gmm(x, wu)
     h, h_vjp = jax.vjp(functools.partial(_gated_act, act), g, u)
-    dh = gmm(dyf, tr(wo.astype(jnp.float32)))    # (E, C, F)
+    dh = gmm(dy, tr(wo))                         # (E, C, F)
     dg, du = h_vjp(dh)
-    dx = (gmm(dg, tr(wg.astype(jnp.float32)))
-          + gmm(du, tr(wu.astype(jnp.float32))))
-    dwg = gmm(tr(xf), dg)                        # (E, D, F)
-    dwu = gmm(tr(xf), du)
-    dwo = gmm(tr(h), dyf)                        # (E, F, D)
-    return (dx.astype(x.dtype), dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwo.astype(wo.dtype))
+    dx = gmm(dg, tr(wg)) + gmm(du, tr(wu))
+    dwg = gmm(tr(x), dg, out_dtype=wg.dtype)     # (E, D, F)
+    dwu = gmm(tr(x), du, out_dtype=wu.dtype)
+    dwo = gmm(tr(h), dy, out_dtype=wo.dtype)     # (E, F, D)
+    return dx.astype(x.dtype), dwg, dwu, dwo
 
 
 _grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)

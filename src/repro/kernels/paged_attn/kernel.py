@@ -8,14 +8,19 @@ C=1 is the classic decode step; C>1 serves chunked prefill and the
 speculative-decode verify chunk (queries occupy the CONTIGUOUS positions
 ``pos[b] .. pos[b] + C - 1`` — ``pos`` is the FIRST query's position).
 
-Grid: (B, KH, nbt) — the innermost (table-entry) dimension is sequential
-on TPU, so the online-softmax accumulators persist in VMEM scratch
-across j-steps, exactly like the flash kernel's k-dimension.
+Grid: (B, nbt) — the innermost (table-entry) dimension is sequential on
+TPU, so the online-softmax accumulators persist in VMEM scratch across
+j-steps, exactly like the flash kernel's k-dimension.  Each step DMAs
+one pool block with ALL its kv heads and loops over the heads inside
+the kernel: a one-head block would put a 1 on the pool's second-minor
+(head) dim, which the TPU's (8, 128) tiling cannot address.
 
-BlockSpec tiling (all VMEM):
-  q    : (1, 1, C, G, Dq) indexed (b, h)          — G = H // KH query heads
-  k,v  : (1, bl, 1, D*)   indexed (bt[b, j], h)   — the paged indirection
-  out  : (1, 1, C, G, Dv) indexed (b, h)
+BlockSpec tiling (all VMEM; pools are viewed as (n_blocks, bl, KH*D)):
+  q    : (1, KH, C*G, Dq) indexed b          — G = H // KH query heads,
+                                               row r = c*G + g
+  k,v  : (1, bl, KH*D*)   indexed bt[b, j]   — the paged indirection
+  scale: (1, bl, KH)      indexed bt[b, j]   — quantized pools only
+  out  : (1, KH, C*G, Dv) indexed b
 
 Blocks whose first row lies beyond the LAST query's position (or
 entirely left of the sliding window) are skipped with ``pl.when`` — a
@@ -38,14 +43,16 @@ NEG_INF = -1.0e30
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, window: int, softcap: float,
-                  block_len: int, n_q: int, quantized: bool):
+                  block_len: int, n_q: int, group: int, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    KH, CG, Dv = acc_scr.shape
+    Dq = q_ref.shape[-1]
 
     @pl.when(j == 0)
     def _init():
@@ -62,44 +69,42 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(visible)
     def _compute():
-        C, G = m_scr.shape
-        q = q_ref[0, 0].astype(jnp.float32)        # (C, G, Dq)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (bl, Dq)
-        v = v_ref[0, :, 0].astype(jnp.float32)     # (bl, Dv)
-        if quantized:
-            # dequantize the DMA'd pool rows in-register: per-(position,
-            # kv-head) scales ride the same block-table indirection
-            k = k * ks_ref[0, :, 0][:, None]       # (bl,) scales
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q.reshape(C * G, -1), k, (((1,), (1,)), ((), ()))
-        ).reshape(C, G, block_len) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        kpos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        qpos = p0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = base + jax.lax.broadcasted_iota(jnp.int32, (CG, block_len), 1)
+        qpos = p0 + jax.lax.broadcasted_iota(
+            jnp.int32, (CG, block_len), 0) // group
         ok = kpos <= qpos
         if window:
             ok = ok & (kpos > qpos - window)
-        s = jnp.where(ok, s, NEG_INF)
+        for h in range(KH):
+            q = q_ref[0, h].astype(jnp.float32)                  # (CG, Dq)
+            k = k_ref[0, :, h * Dq:(h + 1) * Dq].astype(jnp.float32)
+            v = v_ref[0, :, h * Dv:(h + 1) * Dv].astype(jnp.float32)
+            if quantized:
+                # dequantize the DMA'd pool rows in-register: per-(position,
+                # kv-head) scales ride the same block-table indirection
+                k = k * ks_ref[0, :, h:h + 1]                    # (bl, 1)
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        # mask the probabilities, not just the scores: a query row with
-        # no visible position yet has m_new == NEG_INF, and
-        # exp(NEG_INF - NEG_INF) would be 1, not 0
-        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[..., None] + jax.lax.dot_general(
-            p.reshape(C * G, block_len), v, (((1,), (0,)), ((), ()))
-        ).reshape(C, G, -1)
-        m_scr[...] = m_new
+            m_prev = m_scr[h]                                    # (CG, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # mask the probabilities, not just the scores: a query row
+            # with no visible position yet has m_new == NEG_INF, and
+            # exp(NEG_INF - NEG_INF) would be 1, not 0
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())))
+            m_scr[h] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom[..., None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_bhgd(q, k_pool, v_pool, block_table, pos, *,
@@ -116,7 +121,7 @@ def paged_attention_bhgd(q, k_pool, v_pool, block_table, pos, *,
     round-trip.  ``out_dtype`` overrides the output dtype (required when
     the pool dtype is the quantized storage dtype)."""
     B, KH, C, G, Dq = q.shape
-    bl = k_pool.shape[1]
+    n_blocks, bl = k_pool.shape[:2]
     Dv = v_pool.shape[-1]
     nbt = block_table.shape[1]
     quantized = k_scale is not None
@@ -124,41 +129,38 @@ def paged_attention_bhgd(q, k_pool, v_pool, block_table, pos, *,
         out_dtype = v_pool.dtype
 
     kern = functools.partial(_paged_kernel, scale=scale, window=window,
-                             softcap=softcap, block_len=bl, n_q=C,
+                             softcap=softcap, block_len=bl, n_q=C, group=G,
                              quantized=quantized)
     in_specs = [
-        pl.BlockSpec((1, 1, C, G, Dq),
-                     lambda b, h, j, bt, pos: (b, h, 0, 0, 0)),
-        pl.BlockSpec((1, bl, 1, Dq),
-                     lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, bl, 1, Dv),
-                     lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
+        pl.BlockSpec((1, KH, C * G, Dq), lambda b, j, bt, pos: (b, 0, 0, 0)),
+        pl.BlockSpec((1, bl, KH * Dq), lambda b, j, bt, pos: (bt[b, j], 0, 0)),
+        pl.BlockSpec((1, bl, KH * Dv), lambda b, j, bt, pos: (bt[b, j], 0, 0)),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [q.reshape(B, KH, C * G, Dq),
+                k_pool.reshape(n_blocks, bl, KH * Dq),
+                v_pool.reshape(n_blocks, bl, KH * Dv)]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bl, 1),
-                         lambda b, h, j, bt, pos: (bt[b, j], 0, h)),
-            pl.BlockSpec((1, bl, 1),
-                         lambda b, h, j, bt, pos: (bt[b, j], 0, h)),
-        ]
+            pl.BlockSpec((1, bl, KH), lambda b, j, bt, pos: (bt[b, j], 0, 0)),
+        ] * 2
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, nbt),
+        grid=(B, nbt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, C, G, Dv),
-                               lambda b, h, j, bt, pos: (b, h, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, C * G, Dv),
+                               lambda b, j, bt, pos: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((C, G), jnp.float32),
-            pltpu.VMEM((C, G), jnp.float32),
-            pltpu.VMEM((C, G, Dv), jnp.float32),
+            pltpu.VMEM((KH, C * G, 1), jnp.float32),
+            pltpu.VMEM((KH, C * G, 1), jnp.float32),
+            pltpu.VMEM((KH, C * G, Dv), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, C, G, Dv), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KH, C * G, Dv), out_dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
+    return out.reshape(B, KH, C, G, Dv)

@@ -9,6 +9,7 @@ import argparse
 
 from repro.federated.server import ServerConfig
 from repro.federated.simulation import SimulationConfig, run_deepfusion
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.checkpoint import save_pytree
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--save", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     small = dict(vocab_size=args.vocab, dtype="float32", remat=False,
                  attn_chunk_q=32, attn_chunk_k=32, loss_chunk=32)

@@ -11,12 +11,15 @@ over the compiled HLO that proves an ``all-to-all`` has matmul work it
 is dataflow-independent of — the structural precondition for XLA's
 latency-hiding scheduler to actually run the collective concurrently
 with compute (what ``cfg.overlap_a2a``'s half-batch split buys).
+
+``tpu_kernels`` names the Pallas kernels a lowered module hands the TPU
+compiler.
 """
 from __future__ import annotations
 
 import math
 import re
-from collections import defaultdict
+from collections import Counter, defaultdict
 from typing import Dict, List, Set, Tuple
 
 _DTYPE_BYTES = {
@@ -41,6 +44,20 @@ def _shape_bytes(m) -> int:
         if d:
             n *= int(d)
     return n * _DTYPE_BYTES[dt]
+
+
+_KERNEL_NAME_RE = re.compile(r'kernel_name = "([^"]+)"')
+
+
+def tpu_kernels(stablehlo_text: str) -> Counter:
+    """Pallas TPU kernels in a lowered (StableHLO) module: kernel name ->
+    number of ``tpu_custom_call`` ops that run it."""
+    out: Counter = Counter()
+    for line in stablehlo_text.splitlines():
+        if "@tpu_custom_call(" in line:
+            m = _KERNEL_NAME_RE.search(line)
+            out[m.group(1) if m else "?"] += 1
+    return out
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, int]:

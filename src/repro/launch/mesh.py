@@ -12,18 +12,29 @@ Production target: TPU v5e, 256 chips/pod.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes over the first ``prod(shape)``
+    devices: the model code places arrays with ``with_sharding_constraint``
+    and ``shard_map``, which need Auto axes (``jax.make_mesh`` defaults to
+    Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Whatever the current host offers (tests / examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+def make_host_mesh(n_devices=None):
+    """(data=1, model=n) over the host's first ``n_devices`` devices
+    (default: all of them)."""
+    n = len(jax.devices()) if n_devices is None else n_devices
+    return _mesh((1, n), ("data", "model"))
 
 
 def make_fleet_mesh(n_hosts=None):
@@ -41,7 +52,7 @@ def make_fleet_mesh(n_hosts=None):
             f"fleet mesh wants {n} hosts but only {len(jax.devices())} "
             "devices exist (set XLA_FLAGS="
             "--xla_force_host_platform_device_count=N for fake hosts)")
-    return jax.make_mesh((n,), ("hosts",))
+    return _mesh((n,), ("hosts",))
 
 
 def make_decode_mesh(n_devices=None):
@@ -62,7 +73,7 @@ def make_decode_mesh(n_devices=None):
     compute.
     """
     d = len(jax.devices()) if n_devices is None else n_devices
-    return jax.make_mesh(decode_mesh_shape(d), ("data", "model"))
+    return _mesh(decode_mesh_shape(d), ("data", "model"))
 
 
 def decode_mesh_shape(n_devices: int):

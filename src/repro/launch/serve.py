@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_decode_mesh, make_host_mesh
 from repro.models import model as M
 from repro.models.layers import paged_read_path
@@ -125,6 +126,7 @@ def main():
         ap.error("--check-unspeculated requires --speculate")
     if args.check_unquantized and args.kv_dtype not in ("int8", "fp8"):
         ap.error("--check-unquantized requires a quantized --kv-dtype")
+    enable_compile_cache()
 
     cfg = get_config(args.arch, variant=args.variant)
     if args.variant == "reduced":

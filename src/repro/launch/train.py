@@ -25,15 +25,16 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.data.federated import FederatedCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.optim import adamw_init, adamw_update, cosine_schedule
-from repro.sharding import batch_spec, named, opt_state_specs, param_specs
+from repro.sharding import named, opt_state_specs, param_specs
 from repro.checkpoint import save_pytree
 
 
@@ -141,6 +142,55 @@ def run_fleet(args) -> int:
     return 0
 
 
+def train_steps(cfg: ModelConfig, mesh, *, steps: int, batch: int, seq: int,
+                lr: float, seed: int = 0, moment_policy: str = "",
+                log=print):
+    """``steps`` AdamW steps of ``cfg`` on ``mesh``; returns (params,
+    per-step losses).
+
+    Parameters and optimizer state are created in place with the
+    layouts of ``param_specs`` / ``opt_state_specs`` — nothing is built
+    whole on one device first — and every step keeps them there.
+    ``moment_policy`` is the AdamW moment storage ('' | 'bf16' | 'int8',
+    see ``repro.optim.adamw.resolve_moment_policy``).
+    """
+    corpus = FederatedCorpus.build(seed=0, n_devices=4, n_domains=4,
+                                   vocab=cfg.vocab_size)
+    key = jax.random.PRNGKey(seed)
+    init = lambda: M.init_params(key, cfg)
+    init_opt = lambda p: adamw_init(p, policy=moment_policy)
+    shapes = jax.eval_shape(init)
+    pshard = named(mesh, param_specs(shapes, mesh))
+    oshard = named(mesh, opt_state_specs(
+        shapes, mesh, state=jax.eval_shape(init_opt, shapes)))
+    rep = NamedSharding(mesh, P())
+    params = jax.jit(init, out_shardings=pshard)()
+    opt = jax.jit(init_opt, out_shardings=oshard)(params)
+    sched = cosine_schedule(lr, steps, warmup=max(steps // 20, 1))
+
+    def step_fn(params, opt, batch, lr):
+        (loss, metrics), g = jax.value_and_grad(
+            lambda p: M.loss_fn(p, cfg, batch, mesh=mesh), has_aux=True)(params)
+        params, opt, stats = adamw_update(g, opt, params, lr=lr,
+                                          weight_decay=0.01)
+        return params, opt, loss, metrics["accuracy"], stats["grad_norm"]
+
+    losses = []
+    with mesh:
+        jitted = jax.jit(step_fn, out_shardings=(pshard, oshard, rep, rep, rep),
+                         donate_argnums=(0, 1))
+        t0 = time.time()
+        for s in range(steps):
+            b = make_batch(cfg, corpus, s, batch, seq)
+            params, opt, loss, acc, gn = jitted(params, opt, b, sched(s))
+            losses.append(loss)
+            if s % max(steps // 10, 1) == 0 or s == steps - 1:
+                log(f"step {s:4d} loss {float(loss):.4f} "
+                    f"acc {float(acc):.3f} gnorm {float(gn):.2e} "
+                    f"({time.time()-t0:.1f}s)")
+    return params, [float(x) for x in losses]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
@@ -173,6 +223,7 @@ def main():
                     help="assert async rounds on an ideal fleet reproduce "
                          "synchronous train_fleet bit-for-bit")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.fleet > 0:
         raise SystemExit(run_fleet(args))
@@ -184,35 +235,8 @@ def main():
         cfg = cfg.replace(vocab_size=args.vocab)
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh())
-    corpus = FederatedCorpus.build(seed=0, n_devices=4, n_domains=4,
-                                   vocab=cfg.vocab_size)
-
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    opt = adamw_init(params)
-    pshard = named(mesh, param_specs(params, mesh))
-    oshard = {"m": named(mesh, param_specs(params, mesh)),
-              "v": named(mesh, param_specs(params, mesh)),
-              "step": named(mesh, opt_state_specs(params, mesh)["step"])}
-    params = jax.device_put(params, pshard)
-    sched = cosine_schedule(args.lr, args.steps, warmup=max(args.steps // 20, 1))
-
-    def step_fn(params, opt, batch, lr):
-        (loss, metrics), g = jax.value_and_grad(
-            lambda p: M.loss_fn(p, cfg, batch, mesh=mesh), has_aux=True)(params)
-        params, opt, stats = adamw_update(g, opt, params, lr=lr,
-                                          weight_decay=0.01)
-        return params, opt, loss, metrics["accuracy"], stats["grad_norm"]
-
-    with mesh:
-        jitted = jax.jit(step_fn)
-        t0 = time.time()
-        for s in range(args.steps):
-            batch = make_batch(cfg, corpus, s, args.batch, args.seq)
-            params, opt, loss, acc, gn = jitted(params, opt, batch, sched(s))
-            if s % max(args.steps // 10, 1) == 0 or s == args.steps - 1:
-                print(f"step {s:4d} loss {float(loss):.4f} "
-                      f"acc {float(acc):.3f} gnorm {float(gn):.2e} "
-                      f"({time.time()-t0:.1f}s)")
+    params, _ = train_steps(cfg, mesh, steps=args.steps, batch=args.batch,
+                            seq=args.seq, lr=args.lr)
     if args.save:
         save_pytree(params, args.save)
         print("saved", args.save)

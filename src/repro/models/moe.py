@@ -217,8 +217,6 @@ def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
     """x: (B, S, D) with batch sharded over `data_axes`.  ``live``
     (B, S) bool masks dead serving lanes out of routing weights AND
     per-device capacity ranks (see ``_a2a_local``)."""
-    from jax.experimental.shard_map import shard_map
-
     B, S, D = x.shape
     E = cfg.n_experts
     ep_size = mesh.shape[ep_axis]
@@ -256,12 +254,12 @@ def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
         dspec = P(data_axes[0])
     body = functools.partial(_a2a_local, cfg=cfg, ep_axis=ep_axis,
                              ep_size=ep_size, capacity=cap)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(dspec, dspec, dspec, dspec,
                   P(ep_axis), P(ep_axis), P(ep_axis)),
         out_specs=dspec,
-        check_rep=False,
+        check_vma=False,
     )(xt, w, idx, live_t, wg, wu, wo)
 
     out = out.reshape(B, S, D)
@@ -304,8 +302,6 @@ def _replicated_ep_local(xt, w, idx, live, wg, wu, wo, *, cfg: ModelConfig,
 
 def moe_replicated_ep(p, cfg: ModelConfig, x, mesh, live=None):
     """Decode-path MoE: see _replicated_ep_local."""
-    from jax.experimental.shard_map import shard_map
-
     B, S, D = x.shape
     E = cfg.n_experts
     n_dev = mesh.size
@@ -335,11 +331,11 @@ def moe_replicated_ep(p, cfg: ModelConfig, x, mesh, live=None):
     body = functools.partial(_replicated_ep_local, cfg=cfg, axes=axes,
                              capacity=cap)
     espec = P(axes)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None), P(None), P(None), P(None), espec, espec, espec),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )(xt, w, idx, live_t, wg, wu, wo)
     out = out.reshape(B, S, D)
     if cfg.n_shared_experts:

@@ -1,5 +1,4 @@
 from repro.sharding.rules import (
-    abstract_mesh,
     param_specs,
     opt_state_specs,
     batch_spec,
@@ -10,6 +9,6 @@ from repro.sharding.rules import (
     data_axes_of,
 )
 
-__all__ = ["abstract_mesh", "param_specs", "opt_state_specs", "batch_spec",
+__all__ = ["param_specs", "opt_state_specs", "batch_spec",
            "cache_specs", "fleet_specs", "host_resident_bytes", "named",
            "data_axes_of"]

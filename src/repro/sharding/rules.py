@@ -17,25 +17,13 @@ rules match the *trailing* dims, leading axes are padded with None.
 from __future__ import annotations
 
 import re
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.utils.pytree import path_str
 
-
-def abstract_mesh(axis_sizes: Tuple[int, ...], axis_names: Tuple[str, ...]):
-    """Version-compatible ``jax.sharding.AbstractMesh`` constructor.
-
-    JAX <= 0.4.x takes a single tuple of (name, size) pairs; newer
-    releases take (axis_sizes, axis_names) positionally.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 # (path regex, trailing-dims spec) — first match wins.
 _RULES: Tuple[Tuple[str, Tuple], ...] = (

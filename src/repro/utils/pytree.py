@@ -19,6 +19,9 @@ def tree_average(trees):
     """Element-wise average of a list of identically-structured pytrees.
 
     This is the FedAvg / proxy-model operator (paper Fig. 4 and Eq. 13).
+    Leaves held in host memory are moved to the device one at a time
+    and averaged there, so host-resident models never make the host do
+    the arithmetic.
     """
     n = len(trees)
     if n == 0:
@@ -26,7 +29,8 @@ def tree_average(trees):
     if n == 1:
         return trees[0]
     return jax.tree.map(
-        lambda *xs: (sum(x.astype(jnp.float32) for x in xs) / n).astype(xs[0].dtype),
+        lambda *xs: (sum(jnp.asarray(x).astype(jnp.float32) for x in xs)
+                     / n).astype(xs[0].dtype),
         *trees,
     )
 

@@ -15,8 +15,9 @@ import pytest
 from repro.core import distill, tuning
 from repro.core import vaa as vaa_mod
 from repro.data.federated import FederatedCorpus
-from repro.federated.device import (DeviceSpec, device_upload_bytes,
-                                    train_device, train_fleet)
+from repro.federated.device import (DeviceSpec, _device_init, _init_bucket,
+                                    device_upload_bytes, train_device,
+                                    train_fleet)
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.optim import adamw_init, adamw_update, cosine_schedule
@@ -90,6 +91,23 @@ def test_fleet_vmap_matches_per_device(corpus, fleet):
         assert g["arch_id"] == r["arch_id"] == spec.arch_id
         assert g["upload_bytes"] == r["upload_bytes"]
         np.testing.assert_array_equal(g["embedding"], r["embedding"])
+        # uploads wait for the server in host memory, not on the device
+        assert all(isinstance(x, np.ndarray)
+                   for x in jax.tree.leaves(g["params"]))
+
+
+@pytest.mark.parametrize("policy", ["", "bf16", "int8"])
+def test_init_bucket_equals_stacked_device_inits(fleet, policy):
+    specs = [s for s in fleet if s.cfg == CFG_A]
+    params, opt = _init_bucket(specs, 3, policy)
+    inits = [_device_init(s, 3, policy) for s in specs]
+    stack = lambda *xs: np.stack(xs)
+    for got, want in ((params, jax.tree.map(stack, *[p for p, _ in inits])),
+                      (opt, jax.tree.map(stack, *[o for _, o in inits]))):
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), w)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.launch import hlo_analysis as H
@@ -36,7 +36,7 @@ from repro.sharding import rules
 
 from test_serve_chunked import ENGINE_ARCHS, family_batch, run_engine
 
-MESH16 = rules.abstract_mesh((16, 16), ("data", "model"))
+MESH16 = AbstractMesh((16, 16), ("data", "model"))
 
 MULTI = len(jax.devices()) >= 8
 needs_multi = pytest.mark.skipif(
@@ -45,7 +45,7 @@ needs_multi = pytest.mark.skipif(
 
 
 def trivial_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_decode_mesh(1)
 
 
 # ---------------------------------------------------------------------------
@@ -296,9 +296,9 @@ def test_overlap_ok_gate():
                          variant="reduced").replace(overlap_a2a=True)
     dense_cfg = get_config("tinyllama-1.1b",
                            variant="reduced").replace(overlap_a2a=True)
-    mesh = rules.abstract_mesh((2, 4), ("data", "model"))
-    flat = rules.abstract_mesh((1, 8), ("data", "model"))
-    one = rules.abstract_mesh((8, 1), ("data", "model"))
+    mesh = AbstractMesh((2, 4), ("data", "model"))
+    flat = AbstractMesh((1, 8), ("data", "model"))
+    one = AbstractMesh((8, 1), ("data", "model"))
     assert M._overlap_ok(moe_cfg, mesh, 4, None)
     assert M._overlap_ok(moe_cfg, flat, 2, None)
     assert not M._overlap_ok(moe_cfg.replace(overlap_a2a=False), mesh, 4, None)

@@ -8,14 +8,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.models import model as M
 from repro.sharding import rules
 
-MESH = rules.abstract_mesh((16, 16), ("data", "model"))
-MESH3 = rules.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _abstract_params(name):
