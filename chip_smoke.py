@@ -18,9 +18,9 @@
 
 Weights and data are random, drawn from ``--seed``.  Every width is the
 published one; depth and the serving check's dtype are cut, and each
-cut is printed under ``reduced``.  Each part prints its compile and run seconds (run time
-ends at a host sync), its losses, the Pallas kernels compiled into its
-programs (``tpu_custom_call``) and the device's peak memory.  No rate,
+cut is printed under ``reduced``.  Each part prints its wall and compile
+seconds, its losses, the Pallas kernels compiled into its programs
+(``tpu_custom_call``) and the device's peak memory.  No rate,
 MFU or roofline share is printed.  The last line of stdout is one JSON
 object naming the device.  Without a TPU the script exits non-zero and
 prints no result.
@@ -83,10 +83,10 @@ class Recorder:
     """Splits a run into named parts at ``mark`` calls.
 
     For each part: wall seconds, compile seconds (the union of JAX's
-    trace / lower / backend-compile spans inside it), run seconds (the
-    rest; every part ends at a host sync), and the Pallas kernels of the
-    programs compiled in it, read from the lowered modules JAX dumps to
-    ``ir_dir`` (None: no kernel census, e.g. on CPU)."""
+    trace / lower / backend-compile spans inside it), and the Pallas
+    kernels of the programs compiled in it, read from the lowered
+    modules JAX dumps to ``ir_dir`` (None: no kernel census, e.g. on
+    CPU)."""
 
     def __init__(self, ir_dir=None):
         self.ir_dir = ir_dir
@@ -142,7 +142,7 @@ class Recorder:
             rec["kernels"].update(ks)
         extra = " ".join(f"{k} {v}" for k, v in info.items()
                          if v is not None)
-        log_line(f"[{part}] compile {comp:.1f}s run {wall - comp:.1f}s "
+        log_line(f"[{part}] wall {wall:.1f}s compile {comp:.1f}s "
                  f"{extra}".rstrip())
         for module, ks in kernels.items():
             log_line(f"  tpu_custom_call x{sum(ks.values())} in {module}: "
@@ -416,9 +416,8 @@ def main(argv=None) -> int:
         shutil.rmtree(ir_dir, ignore_errors=True)
     for part, r in rec.parts.items():
         ks = ", ".join(f"{k} x{n}" for k, n in sorted(r["kernels"].items()))
-        log_line(f"summary [{part}]: compile {r['compile_s']:.1f}s run "
-                 f"{r['wall_s'] - r['compile_s']:.1f}s; kernels: "
-                 f"{ks or 'none'}")
+        log_line(f"summary [{part}]: wall {r['wall_s']:.1f}s compile "
+                 f"{r['compile_s']:.1f}s; kernels: {ks or 'none'}")
     log_line(f"peak_bytes_in_use: {peak_bytes()}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

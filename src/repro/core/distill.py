@@ -25,6 +25,7 @@ from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.core import vaa as vaa_mod
 from repro.optim import scan_epoch
+from repro.utils import scopes
 
 
 # ---------------------------------------------------------------------------
@@ -60,6 +61,7 @@ def _head_w(params, cfg: ModelConfig):
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
 
+@scopes.scoped(scopes.KD_LOSS)
 def chunked_ce_kl(s_params, s_cfg: ModelConfig, h_s, t_params, t_cfg,
                   h_t, labels, mask, *, temperature: float = 1.0,
                   use_pallas: bool = False):

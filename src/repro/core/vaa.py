@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers
+from repro.utils import scopes
 
 
 def patchify(x, n_patches: int):
@@ -62,6 +63,7 @@ def init_vaa(key, *, n_stages: int, d_student: int, d_teacher: int,
     }
 
 
+@scopes.scoped(scopes.VAA)
 def vaa_apply(p, student_stages: Sequence[jax.Array], *, n_heads: int,
               p_q: int) -> List[jax.Array]:
     """student_stages: J tensors (B, S, d_S) -> J tensors (B, P_q/J, d_T)."""
@@ -95,6 +97,7 @@ def vaa_apply(p, student_stages: Sequence[jax.Array], *, n_heads: int,
     return out
 
 
+@scopes.scoped(scopes.VAA)
 def feature_matching_loss(p, student_stages, teacher_stages, *, n_heads: int,
                           p_q: int):
     """L_FM (Eq. 9): MSE between VAA-blended student and pooled teacher."""

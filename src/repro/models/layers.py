@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.models import quant
 from repro.models.config import ModelConfig
+from repro.utils import scopes
 
 NEG_INF = -1.0e30
 
@@ -52,6 +53,7 @@ def init_norm(cfg: ModelConfig, d: int, dtype):
     return {"scale": jnp.ones((d,), dtype)}
 
 
+@scopes.scoped(scopes.NORM)
 def apply_norm(p, x, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     if "bias" in p:  # layernorm
@@ -301,6 +303,7 @@ def attention_qkv(p, cfg: ModelConfig, x, positions):
     return q, k, v
 
 
+@scopes.scoped(scopes.ATTENTION)
 def attention_full(p, cfg: ModelConfig, x, positions, *, window: int,
                    causal: bool = True):
     """Full-sequence (train / prefill) attention. Returns (out, (k, v))."""
@@ -381,6 +384,7 @@ def paged_read_path(cfg: ModelConfig, C: int, attn: str = "gqa") -> str:
     return path
 
 
+@scopes.scoped(scopes.ATTENTION)
 def attention_decode(p, cfg: ModelConfig, x, pos, cache, *,
                      window: int, mesh=None, block_table=None,
                      write_table=None):
@@ -510,6 +514,7 @@ def mla_latent(p, cfg: ModelConfig, x, positions):
     return ckv, k_rope
 
 
+@scopes.scoped(scopes.ATTENTION)
 def mla_full(p, cfg: ModelConfig, x, positions):
     """Training / prefill MLA.  Returns (out, (ckv, k_rope))."""
     B, S, _ = x.shape
@@ -554,6 +559,7 @@ def _mla_attend(p, cfg: ModelConfig, x, pos, ckv, krope, mesh):
     return v.reshape(B, C, H * vd).astype(x.dtype) @ p["wo"]
 
 
+@scopes.scoped(scopes.ATTENTION)
 def mla_decode(p, cfg: ModelConfig, x, pos, cache,
                mesh=None, block_table=None, write_table=None):
     """Absorbed-matrix MLA decode: attends directly in the latent space.

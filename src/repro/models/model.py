@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.models import layers, moe, quant, ssm
+from repro.utils import scopes
 
 
 # ---------------------------------------------------------------------------
@@ -66,10 +67,14 @@ def _window_for(cfg: ModelConfig, kind: str) -> int:
     return cfg.sliding_window if kind == "local" else 0
 
 
+def _unroll(cfg: ModelConfig, xs) -> int:
+    return jax.tree.leaves(xs)[0].shape[0] if cfg.scan_unroll else 1
+
+
+@scopes.scoped(scopes.STACK)
 def _scan(cfg: ModelConfig, body, init, xs):
-    n = jax.tree.leaves(xs)[0].shape[0]
-    return jax.lax.scan(body, init, xs,
-                        unroll=n if cfg.scan_unroll else 1)
+    """A scan over stacked layers."""
+    return jax.lax.scan(body, init, xs, unroll=_unroll(cfg, xs))
 
 
 def _maybe_remat(cfg: ModelConfig, fn):
@@ -123,7 +128,8 @@ def _block_full(p, cfg: ModelConfig, x, positions, *, kind: str, mesh,
     if "moe" in p:
         ffn_out, aux = moe.apply_moe(p["moe"], cfg, h, mesh)
     else:
-        ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
+        with jax.named_scope(scopes.MLP):
+            ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
     if cfg.post_block_norm:
         ffn_out = layers.apply_norm(p["ln2_post"], ffn_out)
     x = x + ffn_out
@@ -158,7 +164,8 @@ def _block_decode(p, cfg: ModelConfig, x, pos, cache, *, kind: str, mesh,
     if "moe" in p:
         ffn_out, _ = moe.apply_moe(p["moe"], cfg, h, mesh, live=live)
     else:
-        ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
+        with jax.named_scope(scopes.MLP):
+            ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
     if cfg.post_block_norm:
         ffn_out = layers.apply_norm(p["ln2_post"], ffn_out)
     # keep decode activations batch-sharded: without this the
@@ -327,6 +334,7 @@ def init_params(key, cfg: ModelConfig):
     return p
 
 
+@scopes.scoped(scopes.EMBED)
 def _embed(params, cfg: ModelConfig, tokens):
     x = params["embed"][tokens]
     if cfg.embed_scale:
@@ -538,15 +546,19 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
             xx = xx + a
             # cross attention
             h = layers.apply_norm(bp["ln_x"], xx)
-            q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, dec_pos)
-            _, mk, mv = layers.attention_qkv(bp["xattn"], cfg, memory, enc_pos)
-            xa = layers.chunked_attention(
-                q, mk, mv, dec_pos, enc_pos, causal=False,
-                q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
-                unroll=cfg.scan_unroll)
-            xx = xx + xa.reshape(B, S, -1) @ bp["xattn"]["wo"]
+            with jax.named_scope(scopes.ATTENTION):
+                q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, dec_pos)
+                _, mk, mv = layers.attention_qkv(bp["xattn"], cfg, memory,
+                                                 enc_pos)
+                xa = layers.chunked_attention(
+                    q, mk, mv, dec_pos, enc_pos, causal=False,
+                    q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
+                    unroll=cfg.scan_unroll)
+                xa = xa.reshape(B, S, -1) @ bp["xattn"]["wo"]
+            xx = xx + xa
             h = layers.apply_norm(bp["ln2"], xx)
-            xx = xx + layers.apply_mlp(bp["mlp"], cfg, h)
+            with jax.named_scope(scopes.MLP):
+                xx = xx + layers.apply_mlp(bp["mlp"], cfg, h)
             # dict layout matches init_decode_cache so prefill_into_cache
             # can graft the decoder self-KV (kv is attention_full's tuple)
             return xx, {"self": {"k": kv[0], "v": kv[1]},
@@ -571,6 +583,7 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
 # losses
 # ---------------------------------------------------------------------------
 
+@scopes.scoped(scopes.HEAD)
 def chunked_ce(params, cfg: ModelConfig, h, labels, mask):
     """Sequence-chunked CE: never materialises (B, S, V) logits at once.
 
@@ -607,8 +620,9 @@ def chunked_ce(params, cfg: ModelConfig, h, labels, mask):
                 cor_s + jnp.sum(correct * mmf)), 0
 
     body = _maybe_remat(cfg, body) if cfg.remat else body
-    (nll, tok, cor), _ = _scan(
-        cfg, body, (jnp.zeros((), jnp.float32),) * 3, (hc, lc, mc))
+    (nll, tok, cor), _ = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32),) * 3, (hc, lc, mc),
+        unroll=_unroll(cfg, hc))
     return nll, tok, cor
 
 
@@ -698,7 +712,8 @@ def prefill(params, cfg: ModelConfig, batch, *, mesh=None,
     """
     h, _, caches, _ = backbone(params, cfg, batch, mesh=mesh,
                                collect_cache=True)
-    logits = _head(params, cfg, h[:, -1:])[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        logits = _head(params, cfg, h[:, -1:])[:, 0]
     if return_hidden:
         return (logits, h[:, -1]), caches
     return logits, caches
@@ -1139,7 +1154,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *, mesh=None,
         h, new_cache = _chunk_hidden(params, cfg, cache, x, pos[:, None],
                                      mesh=mesh, block_tables=block_tables,
                                      live=lv)
-    return _head(params, cfg, h)[:, 0], new_cache
+    with jax.named_scope(scopes.HEAD):
+        return _head(params, cfg, h)[:, 0], new_cache
 
 
 def _chunk_hidden(params, cfg: ModelConfig, cache, x, pos, *, mesh=None,
@@ -1265,14 +1281,17 @@ def _encdec_decode(params, cfg: ModelConfig, x, pos, cache, *, mesh,
                                          write_table=write_tables)
         x = x + a
         h = layers.apply_norm(bp["ln_x"], x)
-        q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, pos)
-        Ta = cc["k"].shape[1]
-        kpos = jnp.arange(Ta)[None].repeat(B, 0)
-        xa = layers.decode_attention(q, cc["k"], cc["v"], pos, kpos,
-                                     causal=False)
-        x = x + xa.reshape(B, C, -1) @ bp["xattn"]["wo"]
+        with jax.named_scope(scopes.ATTENTION):
+            q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, pos)
+            Ta = cc["k"].shape[1]
+            kpos = jnp.arange(Ta)[None].repeat(B, 0)
+            xa = layers.decode_attention(q, cc["k"], cc["v"], pos, kpos,
+                                         causal=False)
+            xa = xa.reshape(B, C, -1) @ bp["xattn"]["wo"]
+        x = x + xa
         h = layers.apply_norm(bp["ln2"], x)
-        x = x + layers.apply_mlp(bp["mlp"], cfg, h)
+        with jax.named_scope(scopes.MLP):
+            x = x + layers.apply_mlp(bp["mlp"], cfg, h)
         return x, nsc
 
     x, nsc = _scan(cfg, body, x, (params["dec_blocks"], cache["self"],
@@ -1302,9 +1321,9 @@ def _encdec_encode(params, cfg: ModelConfig, cache, frames, *, mesh):
 
     xe, _ = _scan(cfg, enc_body, xe, params["enc_blocks"])
     memory = layers.apply_norm(params["enc_norm"], xe)
-    mk, mv = jax.vmap(
-        lambda bp: layers.attention_qkv(bp["xattn"], cfg, memory, enc_pos)[1:]
-    )(params["dec_blocks"])
+    with jax.named_scope(scopes.ATTENTION):
+        mk, mv = jax.vmap(lambda bp: layers.attention_qkv(
+            bp["xattn"], cfg, memory, enc_pos)[1:])(params["dec_blocks"])
     cache = dict(cache)
     cache["cross"] = {"k": mk.astype(cache["cross"]["k"].dtype),
                       "v": mv.astype(cache["cross"]["v"].dtype)}
@@ -1386,7 +1405,8 @@ def prefill_chunked(params, cfg: ModelConfig, cache, batch, prompt_len, *,
 
     h0 = jnp.zeros((B, D), _dtype(cfg))
     (cache, h_last), _ = jax.lax.scan(body, (cache, h0), (xs, ps))
-    return _head(params, cfg, h_last[:, None])[:, 0], cache
+    with jax.named_scope(scopes.HEAD):
+        return _head(params, cfg, h_last[:, None])[:, 0], cache
 
 
 # ---------------------------------------------------------------------------
@@ -1435,7 +1455,8 @@ def _mtp_draft(params, cfg: ModelConfig, h, tok, pos, *, mesh=None):
                            emb.astype(h.dtype)], axis=-1) @ mp["proj"]
     hout, _, _ = _block_full(mp["block"], cfg, hin, pos[:, None], kind="full",
                              mesh=mesh)
-    return _head(params, cfg, hout)[:, 0], hout[:, 0]
+    with jax.named_scope(scopes.HEAD):
+        return _head(params, cfg, hout)[:, 0], hout[:, 0]
 
 
 def _spec_zero_rejected(cfg: ModelConfig, cache, pos, a, *, k: int,
@@ -1555,7 +1576,8 @@ def _scan_generate_spec(params, cfg: ModelConfig, cache, tok, pos, rem, done,
         lv = jnp.broadcast_to(live[:, None], (B, C))
         hc, cache = _chunk_hidden(params, cfg, cache, x, cpos, mesh=mesh,
                                   block_tables=block_tables, live=lv)
-        logits = _head(params, cfg, hc)                   # (B, C, V)
+        with jax.named_scope(scopes.HEAD):
+            logits = _head(params, cfg, hc)               # (B, C, V)
 
         # ---- accept: emission chain with in-chunk eos/budget stops ----
         # position j's logits verify draft j+1 (j < k) or sample the

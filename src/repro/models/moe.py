@@ -31,6 +31,7 @@ from repro.kernels.moe_dispatch.ops import (capacity_positions,
                                             token_combine, token_dispatch)
 from repro.models.config import ModelConfig
 from repro.models import layers
+from repro.utils import scopes
 
 
 # ---------------------------------------------------------------------------
@@ -57,6 +58,7 @@ def init_moe(key, cfg: ModelConfig, dtype):
 # routing
 # ---------------------------------------------------------------------------
 
+@scopes.scoped(scopes.ROUTER)
 def route(p, cfg: ModelConfig, x, live=None):
     """Returns (weights (T,k), expert_idx (T,k), aux_loss scalar).
 
@@ -91,6 +93,15 @@ def _expert_ffn(cfg: ModelConfig, wg, wu, wo, x):
     return jnp.einsum("ecf,efd->ecd", h, wo)
 
 
+def _with_shared(p, cfg: ModelConfig, x, out):
+    """The routed experts' ``out`` plus the shared experts' MLP of ``x``,
+    where the layer has shared experts."""
+    if not cfg.n_shared_experts:
+        return out
+    with jax.named_scope(scopes.SHARED_EXPERT):
+        return out + layers.apply_mlp(p["shared"], cfg, x)
+
+
 # ---------------------------------------------------------------------------
 # dense path (tests / tiny configs)
 # ---------------------------------------------------------------------------
@@ -103,22 +114,23 @@ def moe_dense(p, cfg: ModelConfig, x, live=None):
     xt = x.reshape(-1, D)
     w, idx, aux = route(p, cfg, xt,
                         None if live is None else live.reshape(-1))
-    if cfg.use_pallas:
-        from repro.kernels.moe_gemm import ops as moe_ops
-        out = moe_ops.moe_ffn(xt, w, idx, p["wi_gate"], p["wi_up"], p["wo"],
-                              act=cfg.act)
-    else:
-        # (E, T, D) all-experts compute
-        h = jnp.einsum("td,edf->etf", xt, p["wi_gate"])
-        h = layers._act(cfg, h) * jnp.einsum("td,edf->etf", xt, p["wi_up"])
-        y_all = jnp.einsum("etf,efd->etd", h, p["wo"])  # (E, T, D)
-        one_hot = jax.nn.one_hot(idx, cfg.n_experts, dtype=xt.dtype)  # (T,k,E)
-        comb = jnp.einsum("tk,tke->te", w.astype(xt.dtype), one_hot)
-        out = jnp.einsum("te,etd->td", comb, y_all)
+    with jax.named_scope(scopes.EXPERTS):
+        if cfg.use_pallas:
+            from repro.kernels.moe_gemm import ops as moe_ops
+            out = moe_ops.moe_ffn(xt, w, idx, p["wi_gate"], p["wi_up"],
+                                  p["wo"], act=cfg.act)
+        else:
+            # (E, T, D) all-experts compute
+            h = jnp.einsum("td,edf->etf", xt, p["wi_gate"])
+            h = layers._act(cfg, h) * jnp.einsum("td,edf->etf", xt,
+                                                 p["wi_up"])
+            y_all = jnp.einsum("etf,efd->etd", h, p["wo"])  # (E, T, D)
+            one_hot = jax.nn.one_hot(idx, cfg.n_experts,
+                                     dtype=xt.dtype)  # (T,k,E)
+            comb = jnp.einsum("tk,tke->te", w.astype(xt.dtype), one_hot)
+            out = jnp.einsum("te,etd->td", comb, y_all)
     out = out.reshape(B, S, D)
-    if cfg.n_shared_experts:
-        out = out + layers.apply_mlp(p["shared"], cfg, x)
-    return out, aux
+    return _with_shared(p, cfg, x, out), aux
 
 
 # ---------------------------------------------------------------------------
@@ -127,6 +139,16 @@ def moe_dense(p, cfg: ModelConfig, x, live=None):
 
 def _pad_experts(E: int, ep: int) -> int:
     return -(-E // ep) * ep
+
+
+def _expert_weights(p, E_pad: int):
+    """The routed experts' (wi_gate, wi_up, wo), padded with zero
+    experts up to ``E_pad``."""
+    ws = (p["wi_gate"], p["wi_up"], p["wo"])
+    padn = E_pad - ws[0].shape[0]
+    if not padn:
+        return ws
+    return tuple(jnp.pad(x, ((0, padn), (0, 0), (0, 0))) for x in ws)
 
 
 def _capacity(cfg: ModelConfig, t_loc: int, E_pad: int, *, align: int) -> int:
@@ -193,23 +215,26 @@ def _a2a_local(xt, w, idx, live, wg, wu, wo, *, cfg: ModelConfig,
     k = idx.shape[1]
     E_loc = wg.shape[0]
     cap = capacity
-
-    # --- routing layout: per (destination device, local expert, slot) ---
-    flat_e = idx.reshape(-1)                     # (T*k,) global expert id
-    flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
-    # dead rows (freed engine slots) neither hold a capacity rank nor
-    # survive the keep mask: they cannot steal an expert's capacity from
-    # a live token on any device
-    pos, keep = capacity_positions(flat_e, cap, valid=jnp.repeat(live, k))
-    # flat buffer layout: (ep_size * E_loc * cap); dest device major
-    slot = flat_e * cap + pos                    # == dest*(E_loc*cap) + ...
-
     stage = dict(cfg=cfg, ep_axis=ep_axis, ep_size=ep_size, E_loc=E_loc,
                  cap=cap)
-    recv = _a2a_dispatch(xt, flat_tok, slot, keep, **stage)
-    y = _a2a_ffn(recv, wg, wu, wo, cfg=cfg)      # (E_loc, ep*cap, D)
-    out = _a2a_combine(y, flat_tok, slot, keep, w, T, **stage)
-    return out.astype(xt.dtype)
+
+    with jax.named_scope(scopes.DISPATCH):
+        # --- routing layout: per (destination device, local expert, slot)
+        flat_e = idx.reshape(-1)                 # (T*k,) global expert id
+        flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
+        # dead rows (freed engine slots) neither hold a capacity rank nor
+        # survive the keep mask: they cannot steal an expert's capacity
+        # from a live token on any device
+        pos, keep = capacity_positions(flat_e, cap,
+                                       valid=jnp.repeat(live, k))
+        # flat buffer layout: (ep_size * E_loc * cap); dest device major
+        slot = flat_e * cap + pos                # == dest*(E_loc*cap) + ...
+        recv = _a2a_dispatch(xt, flat_tok, slot, keep, **stage)
+    with jax.named_scope(scopes.FFN):
+        y = _a2a_ffn(recv, wg, wu, wo, cfg=cfg)  # (E_loc, ep*cap, D)
+    with jax.named_scope(scopes.COMBINE):
+        out = _a2a_combine(y, flat_tok, slot, keep, w, T, **stage)
+        return out.astype(xt.dtype)
 
 
 def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
@@ -239,13 +264,6 @@ def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
     t_loc = max((B * S) // n_data, 1)
     cap = _capacity(cfg, t_loc, E_pad, align=8)  # MXU-aligned
 
-    wg, wu, wo = p["wi_gate"], p["wi_up"], p["wo"]
-    if E_pad != E:
-        padn = E_pad - E
-        wg = jnp.pad(wg, ((0, padn), (0, 0), (0, 0)))
-        wu = jnp.pad(wu, ((0, padn), (0, 0), (0, 0)))
-        wo = jnp.pad(wo, ((0, padn), (0, 0), (0, 0)))
-
     if not data_axes:
         dspec = P(None)
     elif len(data_axes) > 1:
@@ -254,18 +272,18 @@ def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
         dspec = P(data_axes[0])
     body = functools.partial(_a2a_local, cfg=cfg, ep_axis=ep_axis,
                              ep_size=ep_size, capacity=cap)
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(dspec, dspec, dspec, dspec,
-                  P(ep_axis), P(ep_axis), P(ep_axis)),
-        out_specs=dspec,
-        check_vma=False,
-    )(xt, w, idx, live_t, wg, wu, wo)
+    with jax.named_scope(scopes.EXPERTS):
+        wg, wu, wo = _expert_weights(p, E_pad)
+        out = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(dspec, dspec, dspec, dspec,
+                      P(ep_axis), P(ep_axis), P(ep_axis)),
+            out_specs=dspec,
+            check_vma=False,
+        )(xt, w, idx, live_t, wg, wu, wo)
 
     out = out.reshape(B, S, D)
-    if cfg.n_shared_experts:
-        out = out + layers.apply_mlp(p["shared"], cfg, x)
-    return out, aux
+    return _with_shared(p, cfg, x, out), aux
 
 
 def _replicated_ep_local(xt, w, idx, live, wg, wu, wo, *, cfg: ModelConfig,
@@ -281,23 +299,27 @@ def _replicated_ep_local(xt, w, idx, live, wg, wu, wo, *, cfg: ModelConfig,
     cap = capacity
     dev = jax.lax.axis_index(axes)
 
-    flat_e = idx.reshape(-1)
-    flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
-    pos, fits = capacity_positions(flat_e, cap, valid=jnp.repeat(live, k))
-    local = (flat_e // E_loc) == dev
-    keep = local & fits
-    slot = jnp.where(local, flat_e % E_loc, 0) * cap + pos
-    buf = token_dispatch(xt, flat_tok, slot, keep, E_loc * cap,
-                         use_kernel=cfg.use_pallas)
-    buf = buf.reshape(E_loc, cap, D)
-    if cfg.use_pallas:
-        from repro.kernels.moe_gemm import ops as moe_ops
-        y = moe_ops.grouped_ffn(buf, wg, wu, wo, act=cfg.act)
-    else:
-        y = _expert_ffn(cfg, wg, wu, wo, buf)
-    out = token_combine(y.reshape(E_loc * cap, D), flat_tok, slot, keep,
-                        w.reshape(-1), T, use_kernel=cfg.use_pallas)
-    return jax.lax.psum(out.astype(xt.dtype), axes)
+    with jax.named_scope(scopes.DISPATCH):
+        flat_e = idx.reshape(-1)
+        flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
+        pos, fits = capacity_positions(flat_e, cap,
+                                       valid=jnp.repeat(live, k))
+        local = (flat_e // E_loc) == dev
+        keep = local & fits
+        slot = jnp.where(local, flat_e % E_loc, 0) * cap + pos
+        buf = token_dispatch(xt, flat_tok, slot, keep, E_loc * cap,
+                             use_kernel=cfg.use_pallas)
+        buf = buf.reshape(E_loc, cap, D)
+    with jax.named_scope(scopes.FFN):
+        if cfg.use_pallas:
+            from repro.kernels.moe_gemm import ops as moe_ops
+            y = moe_ops.grouped_ffn(buf, wg, wu, wo, act=cfg.act)
+        else:
+            y = _expert_ffn(cfg, wg, wu, wo, buf)
+    with jax.named_scope(scopes.COMBINE):
+        out = token_combine(y.reshape(E_loc * cap, D), flat_tok, slot, keep,
+                            w.reshape(-1), T, use_kernel=cfg.use_pallas)
+        return jax.lax.psum(out.astype(xt.dtype), axes)
 
 
 def moe_replicated_ep(p, cfg: ModelConfig, x, mesh, live=None):
@@ -321,26 +343,20 @@ def moe_replicated_ep(p, cfg: ModelConfig, x, mesh, live=None):
                                 / E_pad)), 4)
         cap = min(-(-cap // 4) * 4, max(T, 4))
 
-    wg, wu, wo = p["wi_gate"], p["wi_up"], p["wo"]
-    if E_pad != E:
-        padn = E_pad - E
-        wg = jnp.pad(wg, ((0, padn), (0, 0), (0, 0)))
-        wu = jnp.pad(wu, ((0, padn), (0, 0), (0, 0)))
-        wo = jnp.pad(wo, ((0, padn), (0, 0), (0, 0)))
-
     body = functools.partial(_replicated_ep_local, cfg=cfg, axes=axes,
                              capacity=cap)
     espec = P(axes)
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(None), P(None), P(None), P(None), espec, espec, espec),
-        out_specs=P(None),
-        check_vma=False,
-    )(xt, w, idx, live_t, wg, wu, wo)
+    with jax.named_scope(scopes.EXPERTS):
+        wg, wu, wo = _expert_weights(p, E_pad)
+        out = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(None), P(None), P(None), P(None), espec, espec,
+                      espec),
+            out_specs=P(None),
+            check_vma=False,
+        )(xt, w, idx, live_t, wg, wu, wo)
     out = out.reshape(B, S, D)
-    if cfg.n_shared_experts:
-        out = out + layers.apply_mlp(p["shared"], cfg, x)
-    return out, aux
+    return _with_shared(p, cfg, x, out), aux
 
 
 def apply_moe(p, cfg: ModelConfig, x, mesh=None, live=None):

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import quant
+from repro.utils import scopes
 
 
 def _is_frozen(mask_leaf) -> bool:
@@ -97,6 +98,7 @@ def global_norm_clip(grads, max_norm: float):
     return jax.tree.map(lambda g: (g * scale).astype(g.dtype), grads), gnorm
 
 
+@scopes.scoped(scopes.OPTIMIZER)
 def adamw_update(grads, state, params, *, lr, b1: float = 0.9,
                  b2: float = 0.95, eps: float = 1e-8,
                  weight_decay: float = 0.0, freeze_mask=None,
