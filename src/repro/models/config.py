@@ -61,8 +61,9 @@ class ModelConfig:
     # engines force this on so sharded decode keeps single-device
     # semantics exactly, at the cost of larger dispatch buffers.
     moe_dropless: bool = False
-    # expert-parallel implementation: "dense" (loop, small tests),
-    # "a2a" (shard_map all-to-all, production) or "auto"
+    # MoE implementation: "grouped" (one device, ragged_dot), "dense"
+    # (all experts over every token: the tests' reference), "a2a"
+    # (shard_map all-to-all, production), "replicated_ep" or "auto"
     moe_impl: str = "auto"
     # EP-A2A overlap (decode): split the decode step into two batch
     # halves whose MoE dispatch/FFN/combine stages are structurally

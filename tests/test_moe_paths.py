@@ -1,15 +1,114 @@
-"""Sharded MoE execution paths (a2a / replicated_ep) on a forced
-multi-device CPU backend.
+"""The MoE execution paths against the all-experts reference
+``moe_dense``.
 
-XLA's host device count is locked at backend init, so this runs in a
-subprocess with XLA_FLAGS set — the only way to exercise the shard_map
-paths (and their shared dispatch/combine slot layout) under pytest.
+The one-device ``grouped`` path is compared in process, on value and
+gradients.  The sharded paths (a2a / replicated_ep) need a forced
+multi-device CPU backend: XLA's host device count is locked at backend
+init, so they run in a subprocess with XLA_FLAGS set — the only way to
+exercise the shard_map paths (and their shared dispatch/combine slot
+layout) under pytest.
 """
 import os
 import subprocess
 import sys
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh
+
+from repro.models import moe
+from repro.models.config import ModelConfig
+
+# Qwen1.5-MoE-style (a shared lane of whole experts, top-4 of 8) and
+# DeepSeek-MoE-style (fine-grained: top-6 of 16, two shared experts)
+_GROUPED_CFGS = {
+    "qwen": dict(n_experts=8, top_k=4, moe_d_ff=24, n_shared_experts=4),
+    "deepseek": dict(n_experts=16, top_k=6, moe_d_ff=12,
+                     n_shared_experts=2),
+}
+
+
+def _tiny(family, **kw):
+    return ModelConfig(name=family, arch_type="moe", n_layers=1, d_model=32,
+                       n_heads=2, n_kv_heads=2, head_dim=16, d_ff=64,
+                       vocab_size=64, dtype="float32",
+                       **_GROUPED_CFGS[family], **kw).validate()
+
+
+def _routing_case(cfg, case):
+    """(params, x, live) for one routing case."""
+    p = moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, cfg.d_model))
+    live = None
+    if case == "skewed":
+        # every token's top-k are the same k experts: most groups empty
+        bias = jnp.zeros((cfg.n_experts,)).at[:cfg.top_k].set(
+            jnp.arange(cfg.top_k, 0, -1) * 10.0)
+        x = x.at[..., 0].set(1.0)
+        p = dict(p, router=p["router"].at[0].add(bias))
+    elif case == "live":
+        live = jnp.arange(24).reshape(2, 12) % 3 != 0
+    return p, x, live
+
+
+@pytest.mark.parametrize("case", ["random", "skewed", "live"])
+@pytest.mark.parametrize("family", sorted(_GROUPED_CFGS))
+def test_grouped_matches_dense(family, case):
+    cfg = _tiny(family)
+    p, x, live = _routing_case(cfg, case)
+    ct = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def loss(impl):
+        c = cfg.replace(moe_impl=impl)
+
+        def f(p, x):
+            out, aux = moe.apply_moe(p, c, x, live=live)
+            return jnp.sum(out * ct) + aux, out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+    (ld, out_d), (gp_d, gx_d) = loss("dense")
+    (lg, out_g), (gp_g, gx_g) = loss("grouped")
+    np.testing.assert_allclose(out_g, out_d, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lg, ld, rtol=1e-5)
+    np.testing.assert_allclose(gx_g, gx_d, rtol=1e-5, atol=1e-5)
+    assert set(gp_g) == {"router", "wi_gate", "wi_up", "wo", "shared"}
+    for name in gp_d:
+        for a, b in zip(jax.tree.leaves(gp_g[name]),
+                        jax.tree.leaves(gp_d[name])):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5,
+                                       err_msg=name)
+    if case == "live":
+        dead = ~np.asarray(live)
+        np.testing.assert_array_equal(
+            np.asarray(out_g)[dead],
+            np.asarray(moe._with_shared(p, cfg, x, jnp.zeros_like(x)))[dead])
+    if case == "skewed":
+        _, idx, _ = moe.route(p, cfg, x.reshape(-1, cfg.d_model))
+        assert len(np.unique(np.asarray(idx))) == cfg.top_k
+
+
+@pytest.mark.parametrize("mesh_kind, use_pallas, path", [
+    ("none", False, "grouped"),
+    ("one_device", False, "grouped"),
+    ("none", True, "dense"),
+])
+def test_auto_picks_the_one_device_path(monkeypatch, mesh_kind, use_pallas,
+                                        path):
+    taken = []
+    for name in ("grouped", "dense"):
+        monkeypatch.setattr(moe, f"moe_{name}",
+                            lambda p, cfg, x, live=None, name=name:
+                            taken.append(name) or (x, 0.0))
+    cfg = _tiny("qwen", use_pallas=use_pallas)
+    mesh = (None if mesh_kind == "none" else
+            Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                 ("data", "model")))
+    x = jnp.zeros((1, 4, cfg.d_model))
+    moe.apply_moe(None, cfg, x, mesh)
+    assert taken == [path]
 
 _SCRIPT = r"""
 import os

@@ -4,8 +4,9 @@ The program opens a ``jax.named_scope`` (``repro.utils.scopes``) at each
 layer boundary; XLA keeps it in each instruction's ``op_name``, and the
 benchmark attributes device time to layers by it
 (``bench/harness/scopes.py``).  Here the optimized HLO of a Phase III
-epoch (``moe_dense``) and of a Phase II distillation epoch with VAA, each
-on the XLA and the Pallas path (kernels in interpret mode), and of an
+epoch (``moe_grouped`` on the XLA path, ``moe_dense`` on the Pallas
+path) and of a Phase II distillation epoch with VAA, each on the XLA and
+the Pallas path (kernels in interpret mode), and of an
 expert-parallel ``moe_a2a`` forward on four CPU devices is compiled, and
 every ``dot``/``convolution`` of it, forward and backward, must name a
 layer.
@@ -120,7 +121,7 @@ TUNE = {scopes.ATTENTION, scopes.ROUTER, scopes.EXPERTS,
 DISTILL = {scopes.ATTENTION, scopes.MLP, scopes.VAA, scopes.KD_LOSS}
 # program -> (what compiles it, the layers its matmuls' scopes must show)
 PROGRAMS = {
-    "tune_xla": (lambda tmp: _tune_hlo(False), TUNE),
+    "tune_xla": (lambda tmp: _tune_hlo(False), TUNE | {scopes.FFN}),
     "tune_pallas": (lambda tmp: _tune_hlo(True), TUNE),
     "distill_xla": (lambda tmp: _distill_hlo(False), DISTILL),
     "distill_pallas": (lambda tmp: _distill_hlo(True), DISTILL),

@@ -14,6 +14,7 @@ may load the TPU library, so nothing here touches it while the module
 is imported.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +26,11 @@ from repro.kernels.kd_loss.ops import ce_from_hidden, ce_kl_from_hidden
 from repro.kernels.moe_dispatch.kernel import gather_scatter_add_rows
 from repro.kernels.moe_gemm.ops import grouped_ffn
 from repro.kernels.paged_attn.ops import paged_decode_attention
+from repro.models import moe
+from repro.models.config import ModelConfig
 
 D, H, DH, E, F, V = 2048, 16, 128, 60, 1408, 151936
+TOP_K = 4
 D_TEACHER = 1024          # gpt2-medium, the widest device model
 HBM_BYTES = 16 * 1024 ** 3
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -43,17 +47,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _lower(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compile(fn, sharding, *shapes):
+    compiled = _lower(fn, sharding, *shapes)
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "kernel missing from the TPU program"
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used} bytes do not fit one v5e chip"
-    return text
+    return compiled
 
 
 def test_flash_attention_fwd(one_chip):
@@ -72,7 +80,7 @@ def test_grouped_ffn_fwd_and_grad(one_chip):
         return jnp.sum(fwd(x, wg, wu, wo).astype(F32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
-                    x, w_in, w_in, w_out)
+                    x, w_in, w_in, w_out).as_text()
     # forward kernel + the grouped-matmul backward kernels
     assert text.count("tpu_custom_call") >= 2
 
@@ -128,3 +136,38 @@ def test_paged_decode_attention(one_chip, C, kv):
             return paged_decode_attention(q, k, v, bt, pos, interpret=False)
 
     _compile(fn, one_chip, *shapes)
+
+
+def test_grouped_moe_fwd_and_grad(one_chip):
+    """The one-chip MoE path at the cell's size (2 x 2048 tokens, top-4
+    of 60): XLA's grouped-matmul custom calls over the T*k routed rows,
+    no (E, T, .) all-experts buffer, and less temporary memory than the
+    all-experts reference at the same shape."""
+    T = 4096
+    cfg = ModelConfig(name="qwen1.5-moe", arch_type="moe", n_layers=1,
+                      d_model=D, n_heads=H, n_kv_heads=H, head_dim=DH,
+                      d_ff=4 * F, vocab_size=V, n_experts=E, top_k=TOP_K,
+                      moe_d_ff=F, dtype="bfloat16").validate()
+    shapes = [((D, E), F32), ((E, D, F), BF16), ((E, D, F), BF16),
+              ((E, F, D), BF16), ((1, T, D), BF16)]
+
+    def fwd(impl):
+        def f(router, wg, wu, wo, x):
+            p = dict(router=router, wi_gate=wg, wi_up=wu, wo=wo)
+            return moe.apply_moe(p, cfg.replace(moe_impl=impl), x)[0]
+        return f
+
+    def grad(impl):
+        return jax.grad(lambda *a: jnp.sum(fwd(impl)(*a).astype(F32)),
+                        argnums=(0, 1, 2, 3, 4))
+
+    all_experts = re.compile(rf"\[{E},{T},\d+\]")
+    for program in (fwd, grad):
+        grouped = _compile(program("grouped"), one_chip, *shapes)
+        text = grouped.as_text()
+        assert "ragged-dot" in text
+        assert not all_experts.search(text)
+        dense = _lower(program("dense"), one_chip, *shapes)
+        assert all_experts.search(dense.as_text())
+        assert (grouped.memory_analysis().temp_size_in_bytes
+                < dense.memory_analysis().temp_size_in_bytes)
