@@ -19,11 +19,17 @@ from repro.models.config import ModelConfig
 from repro.optim import adamw_init, adamw_update, scan_epoch
 from repro.utils.pytree import flatten_with_paths, path_str
 
-_FROZEN = re.compile(r"moe/(wi_gate|wi_up|wo)$|moe/shared/")
+_FROZEN = re.compile(
+    r"moe/(wi_gate|wi_up|wo|e_score_correction_bias)$|moe/shared/")
 
 
 def expert_freeze_mask(params) -> Dict:
-    """True = trainable.  Freezes routed + shared expert FFN weights."""
+    """True = trainable.  Freezes routed + shared expert FFN weights and
+    DeepSeek-V3's router correction bias: that bias has no gradient (it
+    only selects) and moves by its own update rule, whose speed the
+    DeepSeek-V3 report sets to 0 at the end of training; Phase III tunes
+    an already trained MoE, so it stays as it is (weight decay would
+    otherwise shrink it)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     mask = [not _FROZEN.search(path_str(p)) for p, _ in flat]
     return jax.tree_util.tree_unflatten(treedef, mask)

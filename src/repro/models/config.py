@@ -54,6 +54,15 @@ class ModelConfig:
     moe_d_ff: int = 0          # per-expert hidden (0 -> d_ff)
     first_dense_layers: int = 0  # leading layers use dense FFN (deepseek)
     router_aux_coef: float = 0.01
+    # router scores: "softmax" (then top-k, batch-wise balance loss) or
+    # "sigmoid", DeepSeek-V3's router: the top-k of score plus a per-layer
+    # (E,) ``e_score_correction_bias`` that only selects, among the
+    # ``topk_group`` best of ``n_group`` groups; weights renormalised and
+    # scaled by ``routed_scaling_factor``; the sequence-wise balance loss
+    router_score: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
     capacity_factor: float = 1.25
     # size expert-parallel buffers to the worst case (t_loc * top_k per
     # expert) so NO token is ever dropped.  Capacity drops are a
@@ -102,6 +111,7 @@ class ModelConfig:
     # ---- misc architecture -----------------------------------------------
     act: str = "silu"          # silu | gelu
     norm_type: str = "rmsnorm" # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scale
     post_block_norm: bool = False  # gemma-2 post-attention/post-ffn norms
     mlp_gated: bool = True     # SwiGLU/GeGLU vs plain 2-layer MLP
@@ -165,6 +175,19 @@ class ModelConfig:
             )
         if self.is_moe:
             assert self.top_k > 0, f"{self.name}: MoE requires top_k > 0"
+            assert self.router_score in ("softmax", "sigmoid"), (
+                f"{self.name}: router_score={self.router_score!r}")
+            assert self.n_experts % self.n_group == 0, (
+                f"{self.name}: {self.n_experts} experts in {self.n_group} "
+                f"groups")
+            assert (self.top_k <= self.topk_group * self.n_experts
+                    // self.n_group), (
+                f"{self.name}: top_k={self.top_k} exceeds the experts of "
+                f"{self.topk_group} groups")
+            assert self.router_score == "sigmoid" or (
+                self.n_group == 1 and self.routed_scaling_factor == 1.0), (
+                f"{self.name}: expert groups and scaled weights belong "
+                f"to the sigmoid router")
         if self.arch_type == "encdec":
             assert self.n_enc_layers > 0
         if self.arch_type == "hybrid":
@@ -200,6 +223,9 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         kw.update(n_experts=4, top_k=2, moe_d_ff=128,
                   n_shared_experts=min(cfg.n_shared_experts, 1),
                   first_dense_layers=min(cfg.first_dense_layers, 1))
+        if cfg.n_group > 1:
+            # 2 groups of 2, one kept: the group limit still binds
+            kw.update(n_group=2, topk_group=1)
     if cfg.is_ssm_block:
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_expand=2)
     if cfg.arch_type == "hybrid":

@@ -54,7 +54,7 @@ def init_norm(cfg: ModelConfig, d: int, dtype):
 
 
 @scopes.scoped(scopes.NORM)
-def apply_norm(p, x, eps: float = 1e-6):
+def apply_norm(p, x, eps: float):
     xf = x.astype(jnp.float32)
     if "bias" in p:  # layernorm
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -295,8 +295,8 @@ def attention_qkv(p, cfg: ModelConfig, x, positions):
     k = (x @ p["wk"]).reshape(B, S, KH, Dh)
     v = (x @ p["wv"]).reshape(B, S, KH, Dh)
     if cfg.qk_norm:
-        q = apply_norm(p["q_norm"], q)
-        k = apply_norm(p["k_norm"], k)
+        q = apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.pos_embedding == "rope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -496,7 +496,7 @@ def _mla_queries(p, cfg: ModelConfig, x, positions):
     B, S, _ = x.shape
     H, nd, pr = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim
     if cfg.q_lora_rank:
-        q = apply_norm(p["q_norm"], x @ p["wq_a"]) @ p["wq_b"]
+        q = apply_norm(p["q_norm"], x @ p["wq_a"], cfg.norm_eps) @ p["wq_b"]
     else:
         q = x @ p["wq"]
     q = q.reshape(B, S, H, nd + pr)
@@ -509,7 +509,7 @@ def mla_latent(p, cfg: ModelConfig, x, positions):
     """Compressed KV: returns (ckv (B,S,r), k_rope (B,S,pr))."""
     r = cfg.kv_lora_rank
     kv = x @ p["wkv_a"]
-    ckv = apply_norm(p["kv_norm"], kv[..., :r])
+    ckv = apply_norm(p["kv_norm"], kv[..., :r], cfg.norm_eps)
     k_rope = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
     return ckv, k_rope
 

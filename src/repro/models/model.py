@@ -108,11 +108,12 @@ def _init_block(key, cfg: ModelConfig, *, use_moe: bool, dtype):
     return p
 
 
-def _block_full(p, cfg: ModelConfig, x, positions, *, kind: str, mesh,
+def _block_attn(p, cfg: ModelConfig, x, positions, *, kind: str,
                 causal: bool = True):
-    """Full-sequence sub-layer.  Returns (x, aux, cache_entry)."""
+    """A full-sequence sub-layer's attention half: x plus its attention.
+    Returns (x, cache_entry)."""
     window = _window_for(cfg, kind)
-    h = layers.apply_norm(p["ln1"], x)
+    h = layers.apply_norm(p["ln1"], x, cfg.norm_eps)
     if cfg.attn_type == "mla":
         attn_out, (ckv, kr) = layers.mla_full(p["attn"], cfg, h, positions)
         kv = {"ckv": ckv, "kr": kr}
@@ -121,9 +122,13 @@ def _block_full(p, cfg: ModelConfig, x, positions, *, kind: str, mesh,
                                                  window=window, causal=causal)
         kv = {"k": k, "v": v}
     if cfg.post_block_norm:
-        attn_out = layers.apply_norm(p["ln1_post"], attn_out)
-    x = x + attn_out
-    h = layers.apply_norm(p["ln2"], x)
+        attn_out = layers.apply_norm(p["ln1_post"], attn_out, cfg.norm_eps)
+    return x + attn_out, kv
+
+
+def _block_ffn(p, cfg: ModelConfig, x, *, mesh):
+    """A sub-layer's FFN half: x plus its MLP or MoE.  Returns (x, aux)."""
+    h = layers.apply_norm(p["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
         ffn_out, aux = moe.apply_moe(p["moe"], cfg, h, mesh)
@@ -131,9 +136,16 @@ def _block_full(p, cfg: ModelConfig, x, positions, *, kind: str, mesh,
         with jax.named_scope(scopes.MLP):
             ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
     if cfg.post_block_norm:
-        ffn_out = layers.apply_norm(p["ln2_post"], ffn_out)
+        ffn_out = layers.apply_norm(p["ln2_post"], ffn_out, cfg.norm_eps)
     x = x + ffn_out
-    x = shard_act(x, mesh)
+    return shard_act(x, mesh), aux
+
+
+def _block_full(p, cfg: ModelConfig, x, positions, *, kind: str, mesh,
+                causal: bool = True):
+    """Full-sequence sub-layer.  Returns (x, aux, cache_entry)."""
+    x, kv = _block_attn(p, cfg, x, positions, kind=kind, causal=causal)
+    x, aux = _block_ffn(p, cfg, x, mesh=mesh)
     return x, aux, kv
 
 
@@ -147,7 +159,7 @@ def _block_decode(p, cfg: ModelConfig, x, pos, cache, *, kind: str, mesh,
     ``live`` (B, C) bool masks dead serving rows (freed slots, bucket
     pads) out of MoE routing weights and expert-capacity accounting."""
     window = _window_for(cfg, kind)
-    h = layers.apply_norm(p["ln1"], x)
+    h = layers.apply_norm(p["ln1"], x, cfg.norm_eps)
     if cfg.attn_type == "mla":
         attn_out, new_cache = layers.mla_decode(p["attn"], cfg, h, pos, cache,
                                                 mesh=mesh,
@@ -158,16 +170,16 @@ def _block_decode(p, cfg: ModelConfig, x, pos, cache, *, kind: str, mesh,
             p["attn"], cfg, h, pos, cache, window=window,
             mesh=mesh, block_table=block_tables, write_table=write_tables)
     if cfg.post_block_norm:
-        attn_out = layers.apply_norm(p["ln1_post"], attn_out)
+        attn_out = layers.apply_norm(p["ln1_post"], attn_out, cfg.norm_eps)
     x = x + attn_out
-    h = layers.apply_norm(p["ln2"], x)
+    h = layers.apply_norm(p["ln2"], x, cfg.norm_eps)
     if "moe" in p:
         ffn_out, _ = moe.apply_moe(p["moe"], cfg, h, mesh, live=live)
     else:
         with jax.named_scope(scopes.MLP):
             ffn_out = layers.apply_mlp(p["mlp"], cfg, h)
     if cfg.post_block_norm:
-        ffn_out = layers.apply_norm(p["ln2_post"], ffn_out)
+        ffn_out = layers.apply_norm(p["ln2_post"], ffn_out, cfg.norm_eps)
     # keep decode activations batch-sharded: without this the
     # replicated_ep MoE path leaves x replicated and every subsequent
     # attention layer runs the FULL batch on EVERY device (§Perf D3)
@@ -387,7 +399,7 @@ def backbone(params, cfg: ModelConfig, batch: Dict[str, Any], *, mesh=None,
                                      collect_stages=collect_stages)
         aux += a
         caches["blocks"] = c
-        h = layers.apply_norm(params["final_norm"], x)
+        h = layers.apply_norm(params["final_norm"], x, cfg.norm_eps)
         return h, aux, caches, stages
 
     if at == "vlm":
@@ -404,7 +416,7 @@ def backbone(params, cfg: ModelConfig, batch: Dict[str, Any], *, mesh=None,
                                        causal=True, collect_cache=collect_cache,
                                        collect_stages=collect_stages)
         caches["blocks"] = c
-        h = layers.apply_norm(params["final_norm"], x)
+        h = layers.apply_norm(params["final_norm"], x, cfg.norm_eps)
         return h, aux, caches, stages  # caller slices off patch positions
 
     if at == "ssm":
@@ -415,7 +427,7 @@ def backbone(params, cfg: ModelConfig, batch: Dict[str, Any], *, mesh=None,
             bp = inp
             blk = _maybe_remat(cfg, lambda xx: xx + (
                 ssm.ssm_forward(bp["mixer"], cfg,
-                                layers.apply_norm(bp["ln"], xx))))
+                                layers.apply_norm(bp["ln"], xx, cfg.norm_eps))))
             x = blk(x)
             x = shard_act(x, mesh)
             return x, (x if collect_stages else 0)
@@ -423,7 +435,7 @@ def backbone(params, cfg: ModelConfig, batch: Dict[str, Any], *, mesh=None,
         if collect_cache:
             def body_c(x, bp):
                 out, c = ssm.ssm_forward(bp["mixer"], cfg,
-                                         layers.apply_norm(bp["ln"], x),
+                                         layers.apply_norm(bp["ln"], x, cfg.norm_eps),
                                          return_cache=True)
                 x = shard_act(x + out, mesh)
                 return x, (c, x if collect_stages else 0)
@@ -431,7 +443,7 @@ def backbone(params, cfg: ModelConfig, batch: Dict[str, Any], *, mesh=None,
             caches["blocks"] = c
         else:
             x, stages = _scan(cfg, body, x, params["blocks"])
-        h = layers.apply_norm(params["final_norm"], x)
+        h = layers.apply_norm(params["final_norm"], x, cfg.norm_eps)
         if not collect_stages:
             stages = None
         return h, jnp.zeros((), jnp.float32), caches, stages
@@ -462,7 +474,7 @@ def _hybrid_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
         if collect:
             def body(x, bp):
                 out, c = ssm.ssm_forward(bp["mixer"], cfg,
-                                         layers.apply_norm(bp["ln"], x),
+                                         layers.apply_norm(bp["ln"], x, cfg.norm_eps),
                                          return_cache=True)
                 return x + out, c
             return _scan(cfg, body, x, stack)
@@ -471,7 +483,7 @@ def _hybrid_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
             # forward during backward; the inner per-block checkpoint then
             # bounds the live set to ONE block's intermediates (§Perf Z2)
             fn = _maybe_remat(cfg, lambda xx: xx + ssm.ssm_forward(
-                bp["mixer"], cfg, layers.apply_norm(bp["ln"], xx)))
+                bp["mixer"], cfg, layers.apply_norm(bp["ln"], xx, cfg.norm_eps)))
             return fn(x), 0
         return _scan(cfg, body, x, stack)
 
@@ -504,7 +516,7 @@ def _hybrid_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
         if collect_cache:
             caches["tail_attn"] = kv
             caches["tail"] = tc
-    h = layers.apply_norm(params["final_norm"], x)
+    h = layers.apply_norm(params["final_norm"], x, cfg.norm_eps)
     if not collect_stages:
         stages = None
     return h, jnp.zeros((), jnp.float32), caches, stages
@@ -529,7 +541,7 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
         return fn(x), 0
 
     xe, _ = _scan(cfg, enc_body, xe, params["enc_blocks"])
-    memory = layers.apply_norm(params["enc_norm"], xe)
+    memory = layers.apply_norm(params["enc_norm"], xe, cfg.norm_eps)
 
     # --- decoder ---
     dec_pos = jnp.arange(S)[None].repeat(B, 0)
@@ -540,12 +552,12 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
 
     def dec_body(x, bp):
         def fn(xx):
-            h = layers.apply_norm(bp["ln1"], xx)
+            h = layers.apply_norm(bp["ln1"], xx, cfg.norm_eps)
             a, kv = layers.attention_full(bp["attn"], cfg, h, dec_pos,
                                           window=0, causal=True)
             xx = xx + a
             # cross attention
-            h = layers.apply_norm(bp["ln_x"], xx)
+            h = layers.apply_norm(bp["ln_x"], xx, cfg.norm_eps)
             with jax.named_scope(scopes.ATTENTION):
                 q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, dec_pos)
                 _, mk, mv = layers.attention_qkv(bp["xattn"], cfg, memory,
@@ -556,7 +568,7 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
                     unroll=cfg.scan_unroll)
                 xa = xa.reshape(B, S, -1) @ bp["xattn"]["wo"]
             xx = xx + xa
-            h = layers.apply_norm(bp["ln2"], xx)
+            h = layers.apply_norm(bp["ln2"], xx, cfg.norm_eps)
             with jax.named_scope(scopes.MLP):
                 xx = xx + layers.apply_mlp(bp["mlp"], cfg, h)
             # dict layout matches init_decode_cache so prefill_into_cache
@@ -569,7 +581,7 @@ def _encdec_backbone(params, cfg: ModelConfig, batch, *, mesh, collect_cache,
         return xx, (c if collect_cache else 0, xx if collect_stages else 0)
 
     x, (dec_caches, stages) = _scan(cfg, dec_body, x, params["dec_blocks"])
-    h = layers.apply_norm(params["final_norm"], x)
+    h = layers.apply_norm(params["final_norm"], x, cfg.norm_eps)
     caches = {}
     if collect_cache:
         caches = {"self": dec_caches["self"], "cross": dec_caches["cross"],
@@ -644,6 +656,36 @@ def loss_fn(params, cfg: ModelConfig, batch, *, mesh=None):
     return loss + aux, metrics
 
 
+def expert_load(params, cfg: ModelConfig, batch):
+    """(expert layers, n_experts) int32: how many of the batch's T*top_k
+    assignments each expert of each expert layer of a dense/moe decoder
+    receives, in a forward pass on one device."""
+    tokens = batch["tokens"]
+    B, S = tokens.shape
+    positions = jnp.arange(S)[None].repeat(B, 0)
+    x = _embed(params, cfg, tokens)
+    if "dense_blocks" in params:
+        x, _, _, _ = _run_stack(params["dense_blocks"], cfg, x, positions,
+                                pattern=("full",), mesh=None, causal=True,
+                                collect_cache=False)
+
+    def body(x, gp):
+        counts = []
+        for i, kind in enumerate(cfg.attn_pattern):
+            p = gp[f"sub{i}"]
+            x, _ = _block_attn(p, cfg, x, positions, kind=kind)
+            h = layers.apply_norm(p["ln2"], x, cfg.norm_eps)
+            _, idx, _ = moe.route(p["moe"], cfg, h.reshape(B * S, -1),
+                                  n_seq=B)
+            counts.append(jnp.bincount(idx.reshape(-1),
+                                       length=cfg.n_experts))
+            x, _ = _block_ffn(p, cfg, x, mesh=None)
+        return x, jnp.stack(counts)
+
+    _, counts = _scan(cfg, body, x, params["blocks"])
+    return counts.reshape(-1, cfg.n_experts).astype(jnp.int32)
+
+
 def _mtp_loss(params, cfg: ModelConfig, h, batch):
     """DeepSeek-V3 multi-token prediction head (depth 1): predict t+2."""
     tokens, labels = batch["tokens"], batch["labels"]
@@ -651,7 +693,7 @@ def _mtp_loss(params, cfg: ModelConfig, h, batch):
     mp = params["mtp"]
     # combine hidden at t with embedding of token t+1
     emb_next = _embed(params, cfg, jnp.roll(tokens, -1, axis=1))
-    hin = jnp.concatenate([layers.apply_norm(mp["norm"], h),
+    hin = jnp.concatenate([layers.apply_norm(mp["norm"], h, cfg.norm_eps),
                            emb_next.astype(h.dtype)], axis=-1) @ mp["proj"]
     positions = jnp.arange(S)[None].repeat(B, 0)
     hout, _, _ = _block_full(mp["block"], cfg, hin, positions, kind="full",
@@ -686,7 +728,7 @@ def mtp_chain_loss(params, cfg: ModelConfig, batch, *, depth: int,
     total = jnp.zeros((), jnp.float32)
     for j in range(1, depth + 1):
         emb = _embed(params, cfg, jnp.roll(tokens, -j, axis=1))
-        hin = jnp.concatenate([layers.apply_norm(mp["norm"], h),
+        hin = jnp.concatenate([layers.apply_norm(mp["norm"], h, cfg.norm_eps),
                                emb.astype(h.dtype)], axis=-1) @ mp["proj"]
         h, _, _ = _block_full(mp["block"], cfg, hin, positions, kind="full",
                               mesh=mesh)
@@ -1214,13 +1256,13 @@ def _chunk_hidden(params, cfg: ModelConfig, cache, x, pos, *, mesh=None,
     else:
         raise ValueError(at)
 
-    return layers.apply_norm(params["final_norm"], x), new_cache
+    return layers.apply_norm(params["final_norm"], x, cfg.norm_eps), new_cache
 
 
 def _ssm_step(bp, cfg: ModelConfig, x, bc, C: int, n_valid):
     """One Mamba-2 block: the O(1) recurrence for C=1, the SSD chunk
     path (state + conv carry, pad-frozen via ``n_valid``) for C>1."""
-    h = layers.apply_norm(bp["ln"], x)
+    h = layers.apply_norm(bp["ln"], x, cfg.norm_eps)
     if C == 1:
         return ssm.ssm_decode(bp["mixer"], cfg, h, bc)
     return ssm.ssm_prefill_chunk(bp["mixer"], cfg, h, bc, n_valid)
@@ -1274,13 +1316,13 @@ def _encdec_decode(params, cfg: ModelConfig, x, pos, cache, *, mesh,
 
     def body(x, inp):
         bp, sc, cc = inp
-        h = layers.apply_norm(bp["ln1"], x)
+        h = layers.apply_norm(bp["ln1"], x, cfg.norm_eps)
         a, nsc = layers.attention_decode(bp["attn"], cfg, h, pos, sc,
                                          window=0,
                                          block_table=block_tables,
                                          write_table=write_tables)
         x = x + a
-        h = layers.apply_norm(bp["ln_x"], x)
+        h = layers.apply_norm(bp["ln_x"], x, cfg.norm_eps)
         with jax.named_scope(scopes.ATTENTION):
             q, _, _ = layers.attention_qkv(bp["xattn"], cfg, h, pos)
             Ta = cc["k"].shape[1]
@@ -1289,7 +1331,7 @@ def _encdec_decode(params, cfg: ModelConfig, x, pos, cache, *, mesh,
                                          causal=False)
             xa = xa.reshape(B, C, -1) @ bp["xattn"]["wo"]
         x = x + xa
-        h = layers.apply_norm(bp["ln2"], x)
+        h = layers.apply_norm(bp["ln2"], x, cfg.norm_eps)
         with jax.named_scope(scopes.MLP):
             x = x + layers.apply_mlp(bp["mlp"], cfg, h)
         return x, nsc
@@ -1320,7 +1362,7 @@ def _encdec_encode(params, cfg: ModelConfig, cache, frames, *, mesh):
                            causal=False)[0], 0
 
     xe, _ = _scan(cfg, enc_body, xe, params["enc_blocks"])
-    memory = layers.apply_norm(params["enc_norm"], xe)
+    memory = layers.apply_norm(params["enc_norm"], xe, cfg.norm_eps)
     with jax.named_scope(scopes.ATTENTION):
         mk, mv = jax.vmap(lambda bp: layers.attention_qkv(
             bp["xattn"], cfg, memory, enc_pos)[1:])(params["dec_blocks"])
@@ -1451,7 +1493,7 @@ def _mtp_draft(params, cfg: ModelConfig, h, tok, pos, *, mesh=None):
     """
     mp = params["mtp"]
     emb = _embed(params, cfg, tok[:, None])
-    hin = jnp.concatenate([layers.apply_norm(mp["norm"], h[:, None]),
+    hin = jnp.concatenate([layers.apply_norm(mp["norm"], h[:, None], cfg.norm_eps),
                            emb.astype(h.dtype)], axis=-1) @ mp["proj"]
     hout, _, _ = _block_full(mp["block"], cfg, hin, pos[:, None], kind="full",
                              mesh=mesh)

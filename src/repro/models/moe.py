@@ -70,6 +70,8 @@ def init_moe(key, cfg: ModelConfig, dtype):
     }
     if cfg.n_shared_experts:
         p["shared"] = layers.init_mlp(ks[4], cfg, D, F * cfg.n_shared_experts, dtype)
+    if cfg.router_score == "sigmoid":
+        p["e_score_correction_bias"] = jnp.zeros((E,), jnp.float32)
     return p
 
 
@@ -78,11 +80,13 @@ def init_moe(key, cfg: ModelConfig, dtype):
 # ---------------------------------------------------------------------------
 
 @scopes.scoped(scopes.ROUTER)
-def route(p, cfg: ModelConfig, x, live=None):
+def route(p, cfg: ModelConfig, x, live=None, n_seq: int = 1):
     """Returns (weights (T,k), expert_idx (T,k), aux_loss scalar).
 
-    x: (T, D) flat tokens.  Softmax-then-topk routing with the standard
-    load-balance auxiliary loss (GShard / Switch style).
+    x: (T, D) flat tokens, ``n_seq`` sequences of T / n_seq.
+    Softmax-then-topk routing with the standard load-balance auxiliary
+    loss (GShard / Switch style); ``router_score`` "sigmoid" takes
+    DeepSeek-V3's router instead (``_sigmoid_route``).
 
     ``live`` (T,) bool marks rows that belong to live engine slots
     (serving): dead rows' routing weights are zeroed, so whatever a
@@ -91,6 +95,8 @@ def route(p, cfg: ModelConfig, x, live=None):
     makes dead lanes invisible to every MoE path.
     """
     logits = x.astype(jnp.float32) @ p["router"]  # (T, E)
+    if cfg.router_score == "sigmoid":
+        return _sigmoid_route(p, cfg, logits, live, n_seq)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, cfg.top_k)
     w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
@@ -103,6 +109,42 @@ def route(p, cfg: ModelConfig, x, live=None):
     fe = jnp.mean(jnp.sum(one_hot, axis=1), axis=0)  # fraction routed per expert
     aux = E * jnp.sum(me * fe) * cfg.router_aux_coef
     return w, idx, aux
+
+
+def _sigmoid_route(p, cfg: ModelConfig, logits, live, n_seq: int):
+    """DeepSeek-V3's router (arXiv:2412.19437 §2.1.2, ``topk_method``
+    noaux_tc): sigmoid scores; the top-k experts by score plus the
+    correction bias, among the ``topk_group`` groups whose two best
+    biased scores sum highest; as weights the unbiased scores of those
+    experts, renormalised and scaled by ``routed_scaling_factor``.  The
+    bias only selects: no gradient reaches it.
+
+    The balance loss is the sequence-wise term (eq. 17-20): per sequence
+    of S tokens, f_i = E/(k S) * (assignments to expert i) and P_i the
+    mean over its tokens of the normalised scores; sum_i f_i P_i,
+    averaged over the sequences."""
+    T, E = logits.shape
+    k, G = cfg.top_k, cfg.n_group
+    scores = jax.nn.sigmoid(logits)
+    sel = jax.lax.stop_gradient(scores + p["e_score_correction_bias"])
+    if G > 1:
+        g = sel.reshape(T, G, E // G)
+        top2 = jax.lax.top_k(g, min(2, E // G))[0]
+        _, keep = jax.lax.top_k(jnp.sum(top2, axis=-1), cfg.topk_group)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(G), axis=1)  # (T,G)
+        sel = jnp.where(jnp.repeat(kept, E // G, axis=1), sel, -jnp.inf)
+    _, idx = jax.lax.top_k(sel, k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w * cfg.routed_scaling_factor
+    if live is not None:
+        w = jnp.where(live[:, None], w, 0.0)
+    S = T // n_seq
+    one_hot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # (T,k,E)
+    f = (E / (k * S)) * jnp.sum(one_hot.reshape(n_seq, S * k, E), axis=1)
+    probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    P = jnp.mean(probs.reshape(n_seq, S, E), axis=1)
+    return w, idx, cfg.router_aux_coef * jnp.mean(jnp.sum(f * P, axis=-1))
 
 
 def _expert_ffn(cfg: ModelConfig, wg, wu, wo, x):
@@ -134,7 +176,7 @@ def moe_dense(p, cfg: ModelConfig, x, live=None):
     B, S, D = x.shape
     xt = x.reshape(-1, D)
     w, idx, aux = route(p, cfg, xt,
-                        None if live is None else live.reshape(-1))
+                        None if live is None else live.reshape(-1), B)
     with jax.named_scope(scopes.EXPERTS):
         if cfg.use_pallas:
             from repro.kernels.moe_gemm import ops as moe_ops
@@ -246,7 +288,7 @@ def moe_grouped(p, cfg: ModelConfig, x, live=None):
     E = cfg.n_experts
     xt = x.reshape(-1, D)
     w, idx, aux = route(p, cfg, xt,
-                        None if live is None else live.reshape(-1))
+                        None if live is None else live.reshape(-1), B)
     T, k = idx.shape
     with jax.named_scope(scopes.EXPERTS):
         with jax.named_scope(scopes.DISPATCH):
@@ -386,7 +428,7 @@ def moe_a2a(p, cfg: ModelConfig, x, mesh, *, data_axes=("data",),
     xt = x.reshape(-1, D)
     live_t = (jnp.ones((B * S,), jnp.bool_) if live is None
               else live.reshape(-1))
-    w, idx, aux = route(p, cfg, xt, None if live is None else live_t)
+    w, idx, aux = route(p, cfg, xt, None if live is None else live_t, B)
 
     # static per-device capacity: tokens_per_device * k * cf / E_pad
     n_data = 1
@@ -469,7 +511,7 @@ def moe_replicated_ep(p, cfg: ModelConfig, x, mesh, live=None):
     xt = x.reshape(-1, D)
     live_t = (jnp.ones((B * S,), jnp.bool_) if live is None
               else live.reshape(-1))
-    w, idx, aux = route(p, cfg, xt, None if live is None else live_t)
+    w, idx, aux = route(p, cfg, xt, None if live is None else live_t, B)
     T = xt.shape[0]
     if cfg.moe_dropless:
         cap = _capacity(cfg, T, E_pad, align=4)

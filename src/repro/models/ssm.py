@@ -156,7 +156,7 @@ def ssm_forward(p, cfg: ModelConfig, x, *, conv_cache=None, init_state=None,
                                  compute_dtype=cfg.ssm_compute_dtype)
     y = y + xs.astype(jnp.float32) * p["D"][None, None, :, None]
     y = y.reshape(B, S, d_inner).astype(x.dtype)
-    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z))
+    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = y @ p["out_proj"]
     if return_cache:
         K = cfg.ssm_conv
@@ -214,7 +214,7 @@ def ssm_prefill_chunk(p, cfg: ModelConfig, x, cache, n_valid=None):
                                  compute_dtype=cfg.ssm_compute_dtype)
     y = y + xs.astype(jnp.float32) * p["D"][None, None, :, None]
     y = y.reshape(B, C, d_inner).astype(x.dtype)
-    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z))
+    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = y @ p["out_proj"]
     # conv tail: the K-1 inputs preceding the valid frontier.  conv_in
     # row b covers chunk-relative positions [-(K-1), C); the tail ends at
@@ -255,7 +255,7 @@ def ssm_decode(p, cfg: ModelConfig, x, cache):
     y = jnp.einsum("bhn,bhpn->bhp", Cs.astype(jnp.float32), h_new)
     y = y + xs.astype(jnp.float32) * p["D"][None, :, None]
     y = y.reshape(B, 1, d_inner).astype(x.dtype)
-    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z))
+    y = layers.apply_norm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
     out = y @ p["out_proj"]
     new_cache = {"state": h_new, "conv": conv_in[:, -(K - 1):]}
     return out, new_cache

@@ -27,6 +27,11 @@ _GROUPED_CFGS = {
     "qwen": dict(n_experts=8, top_k=4, moe_d_ff=24, n_shared_experts=4),
     "deepseek": dict(n_experts=16, top_k=6, moe_d_ff=12,
                      n_shared_experts=2),
+    # DeepSeek-V3's router: sigmoid scores, a correction bias that only
+    # selects, top-2 of 4 groups, scaled weights, sequence-wise balance
+    "sigmoid": dict(n_experts=16, top_k=4, moe_d_ff=12, n_shared_experts=2,
+                    router_score="sigmoid", n_group=4, topk_group=2,
+                    routed_scaling_factor=2.5),
 }
 
 
@@ -40,6 +45,9 @@ def _tiny(family, **kw):
 def _routing_case(cfg, case):
     """(params, x, live) for one routing case."""
     p = moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    if cfg.router_score == "sigmoid":
+        p["e_score_correction_bias"] = 0.1 * jax.random.normal(
+            jax.random.PRNGKey(3), (cfg.n_experts,))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, cfg.d_model))
     live = None
     if case == "skewed":
@@ -48,6 +56,8 @@ def _routing_case(cfg, case):
             jnp.arange(cfg.top_k, 0, -1) * 10.0)
         x = x.at[..., 0].set(1.0)
         p = dict(p, router=p["router"].at[0].add(bias))
+        if cfg.router_score == "sigmoid":   # scores saturate: bias selects
+            p["e_score_correction_bias"] = p["e_score_correction_bias"] + bias
     elif case == "live":
         live = jnp.arange(24).reshape(2, 12) % 3 != 0
     return p, x, live
@@ -74,7 +84,8 @@ def test_grouped_matches_dense(family, case):
     np.testing.assert_allclose(out_g, out_d, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(lg, ld, rtol=1e-5)
     np.testing.assert_allclose(gx_g, gx_d, rtol=1e-5, atol=1e-5)
-    assert set(gp_g) == {"router", "wi_gate", "wi_up", "wo", "shared"}
+    assert set(gp_g) == set(p) >= {"router", "wi_gate", "wi_up", "wo",
+                                   "shared"}
     for name in gp_d:
         for a, b in zip(jax.tree.leaves(gp_g[name]),
                         jax.tree.leaves(gp_d[name])):
@@ -88,6 +99,82 @@ def test_grouped_matches_dense(family, case):
     if case == "skewed":
         _, idx, _ = moe.route(p, cfg, x.reshape(-1, cfg.d_model))
         assert len(np.unique(np.asarray(idx))) == cfg.top_k
+
+
+# (n_experts, top_k, n_group, topk_group, scale): a Moonlight-like
+# router (one group) and a DeepSeek-V3-like one (the group limit binds)
+_SIGMOID_CASES = {
+    "moonlight": (16, 6, 1, 1, 2.446),
+    "v3": (32, 8, 8, 4, 2.5),
+}
+
+
+def _plain_sigmoid_route(logits, bias, k, n_group, topk_group, scale, n_seq,
+                         alpha):
+    """DeepSeek-V3's router token by token in numpy (arXiv:2412.19437
+    §2.1.2 and eq. 17-20): experts, weights by expert, balance term."""
+    s = 1.0 / (1.0 + np.exp(-logits))
+    T, E = s.shape
+    per = E // n_group
+    chosen, weights = [], []
+    for t in range(T):
+        b = s[t] + bias
+        worth = [np.sort(b[g * per:(g + 1) * per])[-2:].sum()
+                 for g in range(n_group)]
+        kept = np.argsort(worth)[::-1][:topk_group]
+        allowed = np.concatenate([np.arange(g * per, (g + 1) * per)
+                                  for g in kept])
+        idx = allowed[np.argsort(b[allowed])[::-1][:k]]
+        w = s[t, idx] / s[t, idx].sum() * scale
+        chosen.append(idx)
+        weights.append(dict(zip(idx.tolist(), w)))
+    S = T // n_seq
+    aux = 0.0
+    for q in range(n_seq):
+        rows = slice(q * S, (q + 1) * S)
+        f = np.zeros(E)
+        for idx in chosen[rows]:
+            f[idx] += E / (k * S)
+        P = (s[rows] / s[rows].sum(-1, keepdims=True)).mean(0)
+        aux += alpha * np.sum(f * P) / n_seq
+    return chosen, weights, aux
+
+
+@pytest.mark.parametrize("case", sorted(_SIGMOID_CASES))
+def test_sigmoid_router_matches_plain_router(case):
+    E, k, n_group, topk_group, scale = _SIGMOID_CASES[case]
+    cfg = ModelConfig(name=case, arch_type="moe", n_layers=1, d_model=32,
+                      n_heads=2, n_kv_heads=2, head_dim=16, d_ff=64,
+                      vocab_size=64, dtype="float32", n_experts=E, top_k=k,
+                      moe_d_ff=12, router_score="sigmoid", n_group=n_group,
+                      topk_group=topk_group, routed_scaling_factor=scale,
+                      router_aux_coef=1e-4).validate()
+    p = moe.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    # a bias of the order of the gaps between scores: it reorders
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(4), (E,))
+    p["e_score_correction_bias"] = bias
+    x = jax.random.normal(jax.random.PRNGKey(5), (3 * 16, cfg.d_model))
+    w, idx, aux = jax.jit(lambda p, x: moe.route(p, cfg, x, n_seq=3))(p, x)
+    logits = np.asarray(x, np.float64) @ np.asarray(p["router"], np.float64)
+    chosen, weights, want_aux = _plain_sigmoid_route(
+        logits, np.asarray(bias, np.float64), k, n_group, topk_group, scale,
+        3, 1e-4)
+    for t in range(x.shape[0]):
+        assert sorted(np.asarray(idx[t]).tolist()) == sorted(
+            chosen[t].tolist()), t
+        got = dict(zip(np.asarray(idx[t]).tolist(), np.asarray(w[t])))
+        for e, v in weights[t].items():
+            # float32 sigmoid and sums against float64: a few ulps
+            np.testing.assert_allclose(got[e], v, rtol=1e-5)
+    np.testing.assert_allclose(aux, want_aux, rtol=1e-5)
+    # the bias moved the choice of some token, and no gradient reaches it
+    _, idx0, _ = moe.route(dict(p, e_score_correction_bias=jnp.zeros(E)),
+                           cfg, x, n_seq=3)
+    assert np.any(np.sort(np.asarray(idx0), 1) != np.sort(np.asarray(idx), 1))
+    g = jax.grad(lambda p: jnp.sum(moe.route(p, cfg, x, n_seq=3)[0])
+                 + moe.route(p, cfg, x, n_seq=3)[2])(p)
+    assert not np.any(np.asarray(g["e_score_correction_bias"]))
+    assert np.any(np.asarray(g["router"]))
 
 
 @pytest.mark.parametrize("mesh_kind, use_pallas, path", [

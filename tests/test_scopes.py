@@ -5,7 +5,8 @@ layer boundary; XLA keeps it in each instruction's ``op_name``, and the
 benchmark attributes device time to layers by it
 (``bench/harness/scopes.py``).  Here the optimized HLO of a Phase III
 epoch (``moe_grouped`` on the XLA path, ``moe_dense`` on the Pallas
-path) and of a Phase II distillation epoch with VAA, each on the XLA and
+path; and of a Moonlight-shaped model: MLA, a dense layer, the sigmoid
+router) and of a Phase II distillation epoch with VAA, each on the XLA and
 the Pallas path (kernels in interpret mode), and of an
 expert-parallel ``moe_a2a`` forward on four CPU devices is compiled, and
 every ``dot``/``convolution`` of it, forward and backward, must name a
@@ -74,8 +75,16 @@ def _reduced_moe(use_pallas):
         use_pallas=use_pallas)
 
 
-def _tune_hlo(use_pallas):
-    cfg = _reduced_moe(use_pallas)
+def _reduced_mla():
+    """Moonlight-16B-A3B's block: MLA with a full-rank query, one dense
+    layer, then experts under the sigmoid router with its bias."""
+    return get_config("deepseek-v3-671b", variant="reduced").replace(
+        n_layers=3, q_lora_rank=0, first_dense_layers=1, n_experts=8,
+        top_k=3, n_shared_experts=2, n_group=1, topk_group=1, n_mtp=0,
+        routed_scaling_factor=2.446, norm_eps=1e-5, remat_attn_chunks=True)
+
+
+def _tune_hlo(cfg):
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     mask, opt = tuning.init_tuning(params)
     tok = jnp.zeros((1, 2, 32), jnp.int32)
@@ -121,8 +130,11 @@ TUNE = {scopes.ATTENTION, scopes.ROUTER, scopes.EXPERTS,
 DISTILL = {scopes.ATTENTION, scopes.MLP, scopes.VAA, scopes.KD_LOSS}
 # program -> (what compiles it, the layers its matmuls' scopes must show)
 PROGRAMS = {
-    "tune_xla": (lambda tmp: _tune_hlo(False), TUNE | {scopes.FFN}),
-    "tune_pallas": (lambda tmp: _tune_hlo(True), TUNE),
+    "tune_xla": (lambda tmp: _tune_hlo(_reduced_moe(False)),
+                 TUNE | {scopes.FFN}),
+    "tune_pallas": (lambda tmp: _tune_hlo(_reduced_moe(True)), TUNE),
+    "tune_mla_xla": (lambda tmp: _tune_hlo(_reduced_mla()),
+                     TUNE | {scopes.FFN, scopes.MLP}),
     "distill_xla": (lambda tmp: _distill_hlo(False), DISTILL),
     "distill_pallas": (lambda tmp: _distill_hlo(True), DISTILL),
     "moe_a2a_4dev": (_a2a_hlo, {scopes.ROUTER, scopes.EXPERTS, scopes.FFN,
