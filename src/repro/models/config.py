@@ -96,6 +96,7 @@ class ModelConfig:
     # bf16 SSD matmul operands (decay/cumsum/state stay f32) - Perf Z3
     ssm_compute_dtype: str = "float32"
     # recompute attention scores per kv-chunk in backward - Perf Z4
+    # (read only by chunked_attention; the splash core saves no scores)
     remat_attn_chunks: bool = False
 
     # ---- hybrid (Zamba-2): shared attention block every k mamba blocks ---
@@ -127,6 +128,7 @@ class ModelConfig:
     loss_chunk: int = 512      # sequence chunking for the CE loss
     # causal-aware chunk skipping in the attention loop (perf opt; see
     # EXPERIMENTS.md §Perf) — skips fully-masked (q-chunk, k-chunk) pairs.
+    # Read only by chunked_attention: the splash core always skips them.
     attn_skip_masked_chunks: bool = False
     use_pallas: bool = False   # Pallas kernels (TPU target / interpret tests)
     # Unroll every lax.scan (incl. chunk loops).  Used by the dry-run's

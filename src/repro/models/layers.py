@@ -1,11 +1,14 @@
 """Core neural layers: norms, RoPE, attention (GQA + MLA), MLPs.
 
 Everything is a pure function over explicit parameter pytrees.  Attention
-ships two execution paths:
+ships three execution paths:
 
+* JAX's Pallas splash kernel (``splash_attention``), a fused causal
+  flash core with a Pallas flash backward, which training / prefill
+  take on one TPU device where the call allows (``attention_core_path``);
 * a chunked online-softmax ("flash-style") jnp implementation — the XLA
-  path used for training / prefill at long sequence lengths without ever
-  materialising the (Sq, Sk) score matrix;
+  path everywhere else, without ever materialising the (Sq, Sk) score
+  matrix;
 * a Pallas TPU kernel (``repro.kernels.flash_attention``) selected with
   ``cfg.use_pallas`` (validated under ``interpret=True`` on CPU).
 
@@ -219,6 +222,132 @@ def chunked_attention(
     return out[:, :Sq].astype(v.dtype)
 
 
+# ---------------------------------------------------------------------------
+# fused causal flash attention (Pallas splash kernel, TPU)
+# ---------------------------------------------------------------------------
+
+# Tried largest first: the first that divides S and keeps a block of q,
+# k or v rows within _SPLASH_TILE_BYTES is the q and kv block of every
+# splash kernel.  1024 was the fastest on a v5e at both benchmark cells'
+# shapes (bf16; qk 128 and qk 192 / v 128); the dq kernel runs out of
+# VMEM above the tile limit (float32 at 1024 x 192, for one).
+_SPLASH_BLOCKS = (1024, 512, 256, 128)
+_SPLASH_TILE_BYTES = 1024 * 256 * 2
+
+
+def splash_block(S: int, width: int, itemsize: int) -> int:
+    """The splash block for S rows of ``width`` values of ``itemsize``
+    bytes; S a multiple of 128."""
+    return next(b for b in _SPLASH_BLOCKS if S % b == 0
+                and b * width * itemsize <= _SPLASH_TILE_BYTES)
+
+
+def splash_attention(q, k, v, *, block: Optional[int] = None,
+                     interpret: Optional[bool] = None):
+    """Causal attention by index through JAX's Pallas TPU splash kernel.
+
+    q: (B,S,H,Dq)  k: (B,S,KH,Dq)  v: (B,S,KH,Dv)  ->  (B,S,H,Dv), with
+    S a multiple of ``block`` (default ``splash_block``).  Query i sees
+    keys 0..i.  Each score tile lives only in VMEM, the softmax
+    statistics stay float32, and blocks wholly above the diagonal are
+    never loaded, in the forward or in the backward's dk/dv and dq
+    kernels, which read only the output and the log-sum-exp the forward
+    saved.  (The kernel's fused backward measured 12-16% faster on a v5e
+    at the benchmark cells' shapes, but keeps a float32 (S/block, H, S,
+    Dq) buffer of partial dq: quadratic in S.)  q, k and v enter the MXU
+    in their own dtype with float32 accumulation; the kernel takes no
+    softmax scale, so q is scaled by 1/sqrt(Dq) in float32 and rounded
+    back to its dtype.  ``interpret`` defaults to running the kernel in
+    interpret mode on the CPU.
+    """
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+
+    B, S, H, Dq = q.shape
+    if block is None:
+        block = splash_block(S, max(Dq, v.shape[-1]), q.dtype.itemsize)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    sizes = sa.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    mask = sa.MultiHeadMask([sa.CausalMask((S, S))] * H)
+    kernel = sa.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                q_seq_shards=1, interpret=interpret)
+    q = (q.astype(jnp.float32) / math.sqrt(Dq)).astype(q.dtype)
+    out = jax.vmap(kernel)(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v)))
+    return jnp.swapaxes(out, 1, 2)
+
+
+def _on_one_tpu() -> bool:
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+_CORE_LOGGED: set = set()
+
+
+def attention_core_path(cfg: ModelConfig, q, v, *, causal: bool,
+                        window: int, softcap: float) -> str:
+    """Which core serves a full-sequence self-attention call:
+    ``"splash"`` (``splash_attention``) or ``"chunked"``
+    (``chunked_attention``), logged once per distinct reason.
+
+    Splash is taken on a process with one TPU device, for a causal call
+    with no sliding window and no logit softcap, S a multiple of 128,
+    and ``use_pallas`` false (which keeps its own Pallas core).  One
+    device: XLA cannot partition a Pallas kernel called outside
+    ``shard_map``, and the core is given no mesh.
+    """
+    B, S, H, Dq = q.shape
+    if cfg.use_pallas:
+        path, why = "chunked", "use_pallas"
+    elif not _on_one_tpu():
+        path, why = "chunked", (f"backend {jax.default_backend()}, "
+                                f"{jax.device_count()} devices")
+    elif not causal:
+        path, why = "chunked", "not causal"
+    elif window:
+        path, why = "chunked", f"window {window}"
+    elif softcap:
+        path, why = "chunked", f"softcap {softcap}"
+    elif S % 128:
+        path, why = "chunked", f"S {S} not a multiple of 128"
+    else:
+        Dv = v.shape[-1]
+        block = splash_block(S, max(Dq, Dv), q.dtype.itemsize)
+        path, why = "splash", (f"causal, {B} x {S} x {H}, qk {Dq} / v {Dv}, "
+                               f"blocks {block}")
+    if (path, why) not in _CORE_LOGGED:
+        _CORE_LOGGED.add((path, why))
+        logging.getLogger(__name__).info("attention core: %s (%s)", path,
+                                         why)
+    return path
+
+
+def full_attention_core(cfg: ModelConfig, q, k, v, positions, *,
+                        causal: bool = True, window: int = 0,
+                        softcap: float = 0.0):
+    """The self-attention core of ``attention_full`` and ``mla_full``:
+    shapes as ``chunked_attention``'s, softmax scale 1/sqrt(Dq), with
+    ``positions`` (B, S) for both queries and keys.
+
+    ``splash_attention`` masks by index where ``chunked_attention``
+    masks by ``positions``; the two agree because every full-sequence
+    caller in ``models/model.py`` passes ``jnp.arange(S)`` for each row,
+    and the one caller that does not (the MTP draft) passes S = 1, which
+    ``attention_core_path`` keeps on the chunked core.
+    """
+    if attention_core_path(cfg, q, v, causal=causal, window=window,
+                           softcap=softcap) == "splash":
+        return splash_attention(q, k, v)
+    return chunked_attention(
+        q, k, v, positions, positions, causal=causal, window=window,
+        softcap=softcap,
+        q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
+        skip_masked_chunks=cfg.attn_skip_masked_chunks,
+        unroll=cfg.scan_unroll, remat_chunks=cfg.remat_attn_chunks)
+
+
 def _constrain_seq(x, mesh, dim):
     """Keep a decode score tensor sharded (batch x data, cache-seq x model).
 
@@ -314,12 +443,9 @@ def attention_full(p, cfg: ModelConfig, x, positions, *, window: int,
             q, k, v, positions, positions, causal=causal, window=window,
             softcap=cfg.attn_logit_softcap)
     else:
-        out = chunked_attention(
-            q, k, v, positions, positions, causal=causal, window=window,
-            softcap=cfg.attn_logit_softcap,
-            q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
-            skip_masked_chunks=cfg.attn_skip_masked_chunks,
-            unroll=cfg.scan_unroll, remat_chunks=cfg.remat_attn_chunks)
+        out = full_attention_core(cfg, q, k, v, positions, causal=causal,
+                                  window=window,
+                                  softcap=cfg.attn_logit_softcap)
     B, S = x.shape[:2]
     return out.reshape(B, S, -1) @ p["wo"], (k, v)
 
@@ -518,19 +644,14 @@ def mla_latent(p, cfg: ModelConfig, x, positions):
 def mla_full(p, cfg: ModelConfig, x, positions):
     """Training / prefill MLA.  Returns (out, (ckv, k_rope))."""
     B, S, _ = x.shape
-    H, nd, pr, vd = cfg.n_heads, cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
+    H, pr, vd = cfg.n_heads, cfg.rope_head_dim, cfg.v_head_dim
     q_nope, q_rope = _mla_queries(p, cfg, x, positions)
     ckv, k_rope = mla_latent(p, cfg, x, positions)
     k_nope = jnp.einsum("bsr,hrn->bshn", ckv, p["wk_b"].astype(ckv.dtype))
     v = jnp.einsum("bsr,hrv->bshv", ckv, p["wv_b"].astype(ckv.dtype))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, S, H, pr))], axis=-1)
-    out = chunked_attention(
-        q, k, v, positions, positions, causal=True,
-        scale=1.0 / math.sqrt(nd + pr),
-        q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
-        skip_masked_chunks=cfg.attn_skip_masked_chunks,
-        unroll=cfg.scan_unroll, remat_chunks=cfg.remat_attn_chunks)
+    out = full_attention_core(cfg, q, k, v, positions)
     return out.reshape(B, S, H * vd) @ p["wo"], (ckv, k_rope)
 
 

@@ -26,7 +26,7 @@ from repro.kernels.kd_loss.ops import ce_from_hidden, ce_kl_from_hidden
 from repro.kernels.moe_dispatch.kernel import gather_scatter_add_rows
 from repro.kernels.moe_gemm.ops import grouped_ffn
 from repro.kernels.paged_attn.ops import paged_decode_attention
-from repro.models import moe
+from repro.models import layers, moe
 from repro.models.config import ModelConfig
 
 D, H, DH, E, F, V = 2048, 16, 128, 60, 1408, 151936
@@ -68,6 +68,24 @@ def test_flash_attention_fwd(one_chip):
     q = ((2, 512, H, DH), BF16)
     fn = functools.partial(flash_attention, causal=True, interpret=False)
     _compile(fn, one_chip, q, q, q)
+
+
+@pytest.mark.parametrize("B,S,Dq,Dv", [(2, 2048, DH, DH), (1, 8192, 192, 128)],
+                         ids=["qwen2moe.tune", "moonlight.tune"])
+def test_splash_attention_fwd_and_grad(one_chip, B, S, Dq, Dv):
+    """The training attention core at both benchmark cells' shapes (the
+    MLA one with qk 192 / v 128): the forward kernel, then the backward's
+    dk/dv and dq kernels."""
+    fwd = functools.partial(layers.splash_attention, interpret=False)
+    q, v = ((B, S, H, Dq), BF16), ((B, S, H, Dv), BF16)
+    _compile(fwd, one_chip, q, q, v)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    q, q, v).as_text()
+    assert text.count("tpu_custom_call") >= 3
 
 
 def test_grouped_ffn_fwd_and_grad(one_chip):
