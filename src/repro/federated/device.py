@@ -58,7 +58,7 @@ class TrafficModel:
     avail_duty: int = 0
 
 
-# named presets for --straggler-profile and the benchmarks
+# named presets for --straggler-profile
 STRAGGLER_PROFILES = {
     "none": TrafficModel(),
     "mild": TrafficModel(median_latency_s=1.0, latency_sigma=0.5,
@@ -236,7 +236,7 @@ def train_device(spec: DeviceSpec, corpus: FederatedCorpus, *, steps: int,
 
     ``compiled=True`` (default) runs the epoch as one scanned program;
     ``compiled=False`` keeps the historical per-step loop (one host sync
-    per step) for equivalence tests and benchmarks.
+    per step) for equivalence tests.
 
     ``state_policy`` ('' | 'bf16' | 'int8') sets the AdamW moment
     storage (see ``repro.optim.adamw.resolve_moment_policy``); the

@@ -1,4 +1,4 @@
-"""End-to-end DeepFusion simulation driver (used by examples/benchmarks).
+"""End-to-end DeepFusion simulation driver (examples, chip_smoke.py).
 
 Builds the federated corpus, trains the device fleet locally, runs the
 three-phase server pipeline, and evaluates the resulting global MoE on

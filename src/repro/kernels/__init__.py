@@ -6,13 +6,11 @@ Each kernel ships three files:
   ref.py    - pure-jnp oracle the tests sweep against.
 
 Kernels:
-  flash_attention - causal/windowed/softcapped blocked attention
-                    (Gemma-2 local+global; prefill hot spot).
   moe_gemm        - grouped expert FFN (E, cap, D) x (E, D, F) for the
-                    all-to-all expert-parallel MoE layer; custom-VJP
-                    backward as grouped GEMMs (trainable).
+                    expert-parallel MoE layers inside ``shard_map``;
+                    custom-VJP backward as grouped GEMMs (trainable).
   moe_dispatch    - fused token permute/unpermute (gather/scatter-add)
-                    shared by all MoE execution paths; custom VJP.
+                    shared by the expert-parallel MoE paths; custom VJP.
   ssd_scan        - Mamba-2 SSD chunked scan (intra-chunk quadratic +
                     carried state).
   kd_loss         - fused CE + KL over large vocabularies straight from

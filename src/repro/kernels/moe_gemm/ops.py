@@ -12,10 +12,7 @@ chain:
     dwg = xᵀ @ dg       dwu = xᵀ @ du       dwo = hᵀ @ dy
 
 g/u/h are recomputed in the backward (activation recomputation), so the
-forward saves only its inputs.  ``moe_ffn`` composes the shared fused
-dispatch/combine utility (``kernels/moe_dispatch``) with ``grouped_ffn``
-and is therefore differentiable end-to-end in tokens, routing weights
-and all three expert weight tensors.
+forward saves only its inputs.
 """
 from __future__ import annotations
 
@@ -25,8 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.moe_gemm.kernel import grouped_ffn_ecd, grouped_matmul
-from repro.kernels.moe_dispatch.ops import (capacity_positions,
-                                            token_combine, token_dispatch)
 
 
 def _on_cpu() -> bool:
@@ -81,27 +76,3 @@ def grouped_ffn(x, wg, wu, wo, *, act: str = "silu", block_c: int = 128,
     if interpret is None:
         interpret = _on_cpu()
     return _grouped_ffn(x, wg, wu, wo, act, (block_c, block_f), interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("act", "interpret"))
-def moe_ffn(xt, w, idx, wg, wu, wo, *, act: str = "silu",
-            interpret: bool | None = None):
-    """Routed token-level MoE for the single-device path: fused dispatch
-    into capacity buffers, grouped kernel, fused weighted combine."""
-    if interpret is None:
-        interpret = _on_cpu()
-    T, D = xt.shape
-    k = idx.shape[1]
-    E = wg.shape[0]
-    cap = max(-(-T * k // E) * 2, 8)  # generous static capacity
-    flat_e = idx.reshape(-1)
-    flat_tok = jnp.arange(T * k, dtype=jnp.int32) // k
-    pos, keep = capacity_positions(flat_e, cap)
-    slot = flat_e * cap + pos
-    buf = token_dispatch(xt, flat_tok, slot, keep, E * cap,
-                         interpret=interpret)
-    y = _grouped_ffn(buf.reshape(E, cap, D), wg, wu, wo, act, (128, 128),
-                     interpret)
-    out = token_combine(y.reshape(E * cap, D), flat_tok, slot, keep,
-                        w.reshape(-1), T, interpret=interpret)
-    return out.astype(xt.dtype)

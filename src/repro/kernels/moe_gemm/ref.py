@@ -48,20 +48,3 @@ def grouped_ffn_bwd_ref(x, wg, wu, wo, dy, *, act: str = "silu"):
     dwu = jnp.einsum("ecd,ecf->edf", xf, du)
     dwo = jnp.einsum("ecf,ecd->efd", h, dyf)
     return dx, dwg, dwu, dwo
-
-
-def moe_ffn_ref(xt, w, idx, wg, wu, wo, *, act: str = "silu"):
-    """Token-level routed MoE oracle (computes all experts, combines).
-
-    xt: (T, D); w: (T, k) routing weights; idx: (T, k) expert ids;
-    wg/wu: (E, D, F); wo: (E, F, D).
-    """
-    E = wg.shape[0]
-    xf = xt.astype(jnp.float32)
-    g = jnp.einsum("td,edf->etf", xf, wg.astype(jnp.float32))
-    g = jax.nn.gelu(g, approximate=True) if act == "gelu" else jax.nn.silu(g)
-    h = g * jnp.einsum("td,edf->etf", xf, wu.astype(jnp.float32))
-    y_all = jnp.einsum("etf,efd->etd", h, wo.astype(jnp.float32))
-    one_hot = jax.nn.one_hot(idx, E, dtype=jnp.float32)
-    comb = jnp.einsum("tk,tke->te", w.astype(jnp.float32), one_hot)
-    return jnp.einsum("te,etd->td", comb, y_all).astype(xt.dtype)

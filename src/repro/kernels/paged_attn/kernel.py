@@ -10,7 +10,7 @@ speculative-decode verify chunk (queries occupy the CONTIGUOUS positions
 
 Grid: (B, nbt) — the innermost (table-entry) dimension is sequential on
 TPU, so the online-softmax accumulators persist in VMEM scratch across
-j-steps, exactly like the flash kernel's k-dimension.  Each step DMAs
+j-steps, as in a flash-attention kernel's k-dimension.  Each step DMAs
 one pool block with ALL its kv heads and loops over the heads inside
 the kernel: a one-head block would put a 1 on the pool's second-minor
 (head) dim, which the TPU's (8, 128) tiling cannot address.

@@ -50,8 +50,7 @@ def make_batch(cfg, corpus, step, batch, seq):
 
 
 # tiny stand-ins for two device families, sized so the fleet smoke runs
-# in seconds on CPU (the real families live in benchmarks/common.py —
-# src never imports from benchmarks)
+# in seconds on CPU
 _FLEET_TINY = dict(vocab_size=256, dtype="float32", remat=False,
                    attn_chunk_q=16, attn_chunk_k=16, loss_chunk=16)
 

@@ -126,10 +126,6 @@ class ModelConfig:
     attn_chunk_q: int = 1024
     attn_chunk_k: int = 1024
     loss_chunk: int = 512      # sequence chunking for the CE loss
-    # causal-aware chunk skipping in the attention loop (perf opt; see
-    # EXPERIMENTS.md §Perf) — skips fully-masked (q-chunk, k-chunk) pairs.
-    # Read only by chunked_attention: the splash core always skips them.
-    attn_skip_masked_chunks: bool = False
     use_pallas: bool = False   # Pallas kernels (TPU target / interpret tests)
     # Unroll every lax.scan (incl. chunk loops).  Used by the dry-run's
     # cost calibration: XLA's cost_analysis counts a while-loop body ONCE,

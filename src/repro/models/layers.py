@@ -1,16 +1,15 @@
 """Core neural layers: norms, RoPE, attention (GQA + MLA), MLPs.
 
-Everything is a pure function over explicit parameter pytrees.  Attention
-ships three execution paths:
+Everything is a pure function over explicit parameter pytrees.
+Full-sequence attention has two cores, chosen in one place
+(``attention_core_path``):
 
 * JAX's Pallas splash kernel (``splash_attention``), a fused causal
   flash core with a Pallas flash backward, which training / prefill
-  take on one TPU device where the call allows (``attention_core_path``);
+  take on one TPU device where the call allows;
 * a chunked online-softmax ("flash-style") jnp implementation — the XLA
   path everywhere else, without ever materialising the (Sq, Sk) score
-  matrix;
-* a Pallas TPU kernel (``repro.kernels.flash_attention``) selected with
-  ``cfg.use_pallas`` (validated under ``interpret=True`` on CPU).
+  matrix.
 
 Decode (single-token query vs. a long cache) uses a direct einsum — it is
 O(S) per step and memory-light.
@@ -128,17 +127,13 @@ def chunked_attention(
     scale: Optional[float] = None,
     q_chunk: int = 1024,
     k_chunk: int = 1024,
-    skip_masked_chunks: bool = False,
     unroll: bool = False,
     remat_chunks: bool = False,
 ):
     """q: (B,Sq,H,Dq)  k: (B,Sk,KH,Dq)  v: (B,Sk,KH,Dv)  ->  (B,Sq,H,Dv).
 
     Never materialises (Sq, Sk); accumulates in f32 with a running
-    max/denominator (online softmax).  With ``skip_masked_chunks`` the
-    (statically known) fully-masked chunk pairs — above the causal
-    diagonal, or outside the sliding window — are skipped entirely, which
-    halves causal-prefill FLOPs and makes local-attention cost O(S·W).
+    max/denominator (online softmax).
     """
     B, Sq, H, Dq = q.shape
     _, Sk, KH, _ = k.shape
@@ -191,32 +186,19 @@ def chunked_attention(
     else:
         kv_step = kv_step_inner
 
-    def q_step(q_blk, qp_blk, qi):
+    def q_step(q_blk, qp_blk):
         m0 = jnp.full((B, KH, G, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KH, G, qc), jnp.float32)
         o0 = jnp.zeros((B, KH, G, qc, Dv), jnp.float32)
-        if skip_masked_chunks:
-            # static chunk-level visibility: q rows of chunk qi span
-            # [qi*qc, qi*qc+qc); k chunk ki spans [ki*kc, ki*kc+kc).
-            carry = (m0, l0, o0)
-            for ki in range(nk):
-                if causal and ki * kc > qi * qc + qc - 1:
-                    continue  # entirely above the causal diagonal
-                if window and (ki * kc + kc - 1) <= (qi * qc - window):
-                    continue  # entirely left of every query's window
-                carry, _ = kv_step(carry, (kr[ki], vr[ki], kp[ki]), q_blk, qp_blk)
-            m, l, o = carry
-        else:
-            (m, l, o), _ = jax.lax.scan(
-                lambda c, x: kv_step(c, x, q_blk, qp_blk), (m0, l0, o0),
-                (kr, vr, kp), unroll=nk if unroll else 1)
+        (m, l, o), _ = jax.lax.scan(
+            lambda c, x: kv_step(c, x, q_blk, qp_blk), (m0, l0, o0),
+            (kr, vr, kp), unroll=nk if unroll else 1)
         return o / jnp.maximum(l, 1e-30)[..., None]
 
-    if skip_masked_chunks or unroll:
-        outs = [q_step(qr[qi], qp[qi], qi) for qi in range(nq)]
-        out = jnp.stack(outs, axis=0)
+    if unroll:
+        out = jnp.stack([q_step(qr[qi], qp[qi]) for qi in range(nq)], axis=0)
     else:
-        out = jax.lax.map(lambda args: q_step(args[0], args[1], 0), (qr, qp))
+        out = jax.lax.map(lambda args: q_step(args[0], args[1]), (qr, qp))
     # (nq, B, KH, G, qc, Dv) -> (B, Sq, H, Dv)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq_p, H, Dv)
     return out[:, :Sq].astype(v.dtype)
@@ -293,15 +275,12 @@ def attention_core_path(cfg: ModelConfig, q, v, *, causal: bool,
     (``chunked_attention``), logged once per distinct reason.
 
     Splash is taken on a process with one TPU device, for a causal call
-    with no sliding window and no logit softcap, S a multiple of 128,
-    and ``use_pallas`` false (which keeps its own Pallas core).  One
-    device: XLA cannot partition a Pallas kernel called outside
+    with no sliding window and no logit softcap, S a multiple of 128.
+    One device: XLA cannot partition a Pallas kernel called outside
     ``shard_map``, and the core is given no mesh.
     """
     B, S, H, Dq = q.shape
-    if cfg.use_pallas:
-        path, why = "chunked", "use_pallas"
-    elif not _on_one_tpu():
+    if not _on_one_tpu():
         path, why = "chunked", (f"backend {jax.default_backend()}, "
                                 f"{jax.device_count()} devices")
     elif not causal:
@@ -344,7 +323,6 @@ def full_attention_core(cfg: ModelConfig, q, k, v, positions, *,
         q, k, v, positions, positions, causal=causal, window=window,
         softcap=softcap,
         q_chunk=cfg.attn_chunk_q, k_chunk=cfg.attn_chunk_k,
-        skip_masked_chunks=cfg.attn_skip_masked_chunks,
         unroll=cfg.scan_unroll, remat_chunks=cfg.remat_attn_chunks)
 
 
@@ -437,15 +415,8 @@ def attention_full(p, cfg: ModelConfig, x, positions, *, window: int,
                    causal: bool = True):
     """Full-sequence (train / prefill) attention. Returns (out, (k, v))."""
     q, k, v = attention_qkv(p, cfg, x, positions)
-    if cfg.use_pallas:
-        from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(
-            q, k, v, positions, positions, causal=causal, window=window,
-            softcap=cfg.attn_logit_softcap)
-    else:
-        out = full_attention_core(cfg, q, k, v, positions, causal=causal,
-                                  window=window,
-                                  softcap=cfg.attn_logit_softcap)
+    out = full_attention_core(cfg, q, k, v, positions, causal=causal,
+                              window=window, softcap=cfg.attn_logit_softcap)
     B, S = x.shape[:2]
     return out.reshape(B, S, -1) @ p["wo"], (k, v)
 

@@ -7,8 +7,7 @@ Execution paths:
   exactly T*k rows (dropless, no capacity, no padding).
 * ``dense`` — every expert computes every token, combined with routing
   weights: E/top_k times the routed work.  Kept as the all-experts
-  reference the tests compare the other paths against, and as the
-  one-device host of the Pallas ``moe_ffn`` under ``use_pallas``.
+  reference the tests compare the other paths against.
 * ``a2a``  — TPU-native expert parallelism inside ``shard_map``: tokens
   live on the "data" axis, experts are sharded over the "model" axis.
   Each device packs its tokens into fixed-capacity per-expert buffers,
@@ -164,34 +163,27 @@ def _with_shared(p, cfg: ModelConfig, x, out):
 
 
 # ---------------------------------------------------------------------------
-# dense path (the all-experts reference; the Pallas moe_ffn's host)
+# dense path (the all-experts reference)
 # ---------------------------------------------------------------------------
 
 def moe_dense(p, cfg: ModelConfig, x, live=None):
-    """x: (B, S, D).  Without ``use_pallas``, computes every expert on
-    every token and selects each token's top-k with a one-hot combine:
-    the reference the tests hold ``moe_grouped`` and the sharded paths
-    to.  Routing is per-token here, so ``live`` only zeroes dead rows'
-    combine weights (no cross-row capacity to protect)."""
+    """x: (B, S, D).  Computes every expert on every token and selects
+    each token's top-k with a one-hot combine: the reference the tests
+    hold ``moe_grouped`` and the sharded paths to.  Routing is per-token
+    here, so ``live`` only zeroes dead rows' combine weights (no
+    cross-row capacity to protect)."""
     B, S, D = x.shape
     xt = x.reshape(-1, D)
     w, idx, aux = route(p, cfg, xt,
                         None if live is None else live.reshape(-1), B)
     with jax.named_scope(scopes.EXPERTS):
-        if cfg.use_pallas:
-            from repro.kernels.moe_gemm import ops as moe_ops
-            out = moe_ops.moe_ffn(xt, w, idx, p["wi_gate"], p["wi_up"],
-                                  p["wo"], act=cfg.act)
-        else:
-            # (E, T, D) all-experts compute
-            h = jnp.einsum("td,edf->etf", xt, p["wi_gate"])
-            h = layers._act(cfg, h) * jnp.einsum("td,edf->etf", xt,
-                                                 p["wi_up"])
-            y_all = jnp.einsum("etf,efd->etd", h, p["wo"])  # (E, T, D)
-            one_hot = jax.nn.one_hot(idx, cfg.n_experts,
-                                     dtype=xt.dtype)  # (T,k,E)
-            comb = jnp.einsum("tk,tke->te", w.astype(xt.dtype), one_hot)
-            out = jnp.einsum("te,etd->td", comb, y_all)
+        # (E, T, D) all-experts compute
+        h = jnp.einsum("td,edf->etf", xt, p["wi_gate"])
+        h = layers._act(cfg, h) * jnp.einsum("td,edf->etf", xt, p["wi_up"])
+        y_all = jnp.einsum("etf,efd->etd", h, p["wo"])  # (E, T, D)
+        one_hot = jax.nn.one_hot(idx, cfg.n_experts, dtype=xt.dtype)  # (T,k,E)
+        comb = jnp.einsum("tk,tke->te", w.astype(xt.dtype), one_hot)
+        out = jnp.einsum("te,etd->td", comb, y_all)
     out = out.reshape(B, S, D)
     return _with_shared(p, cfg, x, out), aux
 
@@ -546,16 +538,13 @@ def apply_moe(p, cfg: ModelConfig, x, mesh=None, live=None):
     bit-identical to the pre-mask behavior.
 
     ``moe_impl="auto"`` takes ``a2a`` on a mesh with a "model" axis and
-    more than one device; otherwise ``dense`` under ``use_pallas`` (its
-    Pallas ``moe_ffn``), else ``grouped``.
+    more than one device, else ``grouped``.
     """
     impl, why = cfg.moe_impl, "moe_impl"
     if impl == "auto":
         if (mesh is not None and "model" in mesh.axis_names
                 and mesh.size > 1):
             impl, why = "a2a", "model axis"
-        elif cfg.use_pallas:
-            impl, why = "dense", "use_pallas: the Pallas moe_ffn"
         else:
             impl, why = "grouped", "no model axis"
     if impl == "grouped":

@@ -44,7 +44,6 @@ bit-identical to ``mesh=None``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional
@@ -95,8 +94,7 @@ class CompiledLRU:
     permanently pins) a fresh prefill/admit executable if cached in an
     unbounded ``lru_cache`` — evicting the per-length jitted callable
     here drops its executables with it.  ``builds`` counts every build
-    (including rebuilds after eviction): the compile-thrash metric the
-    bucketed-admission benchmark reports.
+    (including rebuilds after eviction): the compile-thrash count.
     """
 
     def __init__(self, build: Callable[[Any], Callable], maxsize: int = 32):
@@ -138,15 +136,6 @@ def _scatter_slot_row(cache, sub, slot, bat, seq=None):
             jnp.take(src, 0, axis=bax).astype(dst.dtype))
 
     return jax.tree.map(put, cache, sub, bat, seq)
-
-
-@functools.lru_cache(maxsize=8)
-def _prefill_fn(cfg: ModelConfig, mesh):
-    """Shared jitted prefill (benchmarks use it for the non-engine serving
-    modes).  The engine itself compiles through its bounded per-length
-    ``CompiledLRU`` instead, so sustained open-world traffic cannot pin
-    an executable per prompt length."""
-    return jax.jit(lambda p, b: M.prefill(p, cfg, b, mesh=mesh))
 
 
 class ServeEngine:

@@ -1,7 +1,8 @@
-"""The training / prefill attention core: the splash kernel in interpret
-mode against ``chunked_attention`` and a float32 softmax, the choice of
-core from backend and shape, and the models' full-sequence callers
-under the splash core against the chunked one.
+"""The training / prefill attention core: ``chunked_attention`` against a
+float32 softmax over masks, shapes and dtypes, the splash kernel in
+interpret mode against both, the choice of core from backend and shape,
+and the models' full-sequence callers under the splash core against the
+chunked one.
 
 No chip is needed: ``layers._on_one_tpu`` is patched where a test needs
 the TPU's choice, and the kernel runs in interpret mode on the CPU.
@@ -11,6 +12,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -24,29 +26,99 @@ from repro.models import model as M
 BF16_TOL = 2e-2
 
 
-def _softmax_ref(q, k, v):
-    """Causal float32 softmax attention, GQA by repeating kv heads."""
-    B, S, H, Dq = q.shape
+def _softmax_ref(q, k, v, *, causal=True, window=0, softcap=0.0):
+    """Float32 softmax attention over the dense (Sq, Sk) scores, GQA by
+    repeating kv heads; query i and key j sit at positions i and j."""
+    B, Sq, H, Dq = q.shape
+    Sk = k.shape[1]
     G = H // k.shape[2]
     k, v = (jnp.repeat(x.astype(jnp.float32), G, axis=2) for x in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
                    precision=jax.lax.Precision.HIGHEST) / math.sqrt(Dq)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    w = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    qpos, kpos = jnp.arange(Sq)[:, None], jnp.arange(Sk)[None, :]
+    ok = jnp.ones((Sq, Sk), bool)
+    if causal:
+        ok = ok & (kpos <= qpos)
+    if window:
+        ok = ok & (kpos > qpos - window)
+    w = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v,
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _chunked(q, k, v):
-    B, S = q.shape[:2]
-    pos = jnp.arange(S)[None].repeat(B, 0)
-    return layers.chunked_attention(q, k, v, pos, pos, causal=True,
-                                    q_chunk=128, k_chunk=128)
+def _chunked(q, k, v, *, causal=True, window=0, softcap=0.0, chunk=128):
+    B, Sq = q.shape[:2]
+    q_pos = jnp.arange(Sq)[None].repeat(B, 0)
+    k_pos = jnp.arange(k.shape[1])[None].repeat(B, 0)
+    return layers.chunked_attention(q, k, v, q_pos, k_pos, causal=causal,
+                                    window=window, softcap=softcap,
+                                    q_chunk=chunk, k_chunk=chunk)
 
 
 def _rel_err(a, b):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+
+def _qkv(B, Sq, Sk, H, KH, D, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    return (jax.random.normal(ks[0], (B, Sq, H, D)).astype(dtype),
+            jax.random.normal(ks[1], (B, Sk, KH, D)).astype(dtype),
+            jax.random.normal(ks[2], (B, Sk, KH, D)).astype(dtype))
+
+
+_MASKS = dict(causal=(True, 0, 0.0), window=(True, 24, 0.0),
+              softcap=(True, 0, 50.0), bidirectional=(False, 0, 0.0))
+
+
+@pytest.mark.parametrize("B,Sq,Sk,H,KH,D", [
+    (2, 64, 64, 4, 2, 32),
+    (1, 128, 128, 2, 2, 64),
+    (2, 33, 65, 3, 1, 16),
+    (1, 256, 256, 8, 4, 8),
+])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_chunked_matches_softmax(B, Sq, Sk, H, KH, D, mask):
+    """Float32, 32-row chunks: padded tails (33 and 65 rows), GQA and
+    MQA, Sq != Sk, and each mask."""
+    causal, window, softcap = _MASKS[mask]
+    q, k, v = _qkv(B, Sq, Sk, H, KH, D)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+    np.testing.assert_allclose(np.asarray(_chunked(q, k, v, chunk=32, **kw)),
+                               np.asarray(_softmax_ref(q, k, v, **kw)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_matches_softmax_in_dtype(dtype):
+    """Output in the operands' dtype, accumulated in float32."""
+    q, k, v = _qkv(1, 64, 64, 2, 2, 32, dtype)
+    out = _chunked(q, k, v, chunk=32)
+    assert out.dtype == dtype
+    tol = BF16_TOL if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_softmax_ref(q, k, v), np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("causal,window,softcap", [
+    (True, 0, 0.0), (True, 16, 30.0),
+], ids=["causal", "window_softcap"])
+def test_chunked_grads_match_softmax(causal, window, softcap):
+    """q/k/v gradients through the chunk loop's online softmax, with the
+    window and softcap carried into the backward."""
+    q, k, v = _qkv(1, 48, 48, 2, 2, 16)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+    gc = jax.grad(lambda *a: jnp.sum(_chunked(*a, chunk=16, **kw) ** 2),
+                  (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(_softmax_ref(*a, **kw) ** 2),
+                  (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gc, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 @pytest.mark.parametrize("block", [128, 256])
@@ -96,9 +168,7 @@ _MLA = get_config("deepseek-v3-671b")
     (_QWEN, dict(causal=True, window=4096, softcap=0.0), "window 4096"),
     (_QWEN, dict(causal=True, window=0, softcap=50.0), "softcap 50.0"),
     (_QWEN, dict(causal=False, window=0, softcap=0.0), "not causal"),
-    (_QWEN.replace(use_pallas=True), dict(causal=True, window=0,
-                                          softcap=0.0), "use_pallas"),
-], ids=["cpu", "window", "softcap", "bidirectional", "use_pallas"])
+], ids=["cpu", "window", "softcap", "bidirectional"])
 def test_core_path_keeps_chunked(monkeypatch, cfg, call, why):
     if why != "backend cpu":
         monkeypatch.setattr(layers, "_on_one_tpu", lambda: True)
@@ -125,9 +195,11 @@ def test_core_path_needs_one_device(monkeypatch):
 @pytest.mark.parametrize("cfg,shape", [
     (_QWEN, (2, 2048, 16, 128, 128)),
     (_MLA, (1, 8192, 16, 192, 128)),
-], ids=["qwen2moe.tune", "moonlight.tune"])
+    (_QWEN.replace(use_pallas=True), (2, 2048, 16, 128, 128)),
+], ids=["qwen2moe.tune", "moonlight.tune", "use_pallas"])
 def test_core_path_takes_splash_at_the_cells_shapes(monkeypatch, cfg,
                                                     shape):
+    """Splash at both cells' shapes, whatever ``use_pallas`` says."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     q, v = _shapes(*shape)

@@ -8,6 +8,7 @@ init, so they run in a subprocess with XLA_FLAGS set — the only way to
 exercise the shard_map paths (and their shared dispatch/combine slot
 layout) under pytest.
 """
+import logging
 import os
 import subprocess
 import sys
@@ -18,6 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from repro.models import model as M
 from repro.models import moe
 from repro.models.config import ModelConfig
 
@@ -180,7 +182,7 @@ def test_sigmoid_router_matches_plain_router(case):
 @pytest.mark.parametrize("mesh_kind, use_pallas, path", [
     ("none", False, "grouped"),
     ("one_device", False, "grouped"),
-    ("none", True, "dense"),
+    ("none", True, "grouped"),
 ])
 def test_auto_picks_the_one_device_path(monkeypatch, mesh_kind, use_pallas,
                                         path):
@@ -196,6 +198,41 @@ def test_auto_picks_the_one_device_path(monkeypatch, mesh_kind, use_pallas,
     x = jnp.zeros((1, 4, cfg.d_model))
     moe.apply_moe(None, cfg, x, mesh)
     assert taken == [path]
+
+
+# the sigmoid family also takes latent attention (MLA), as DeepSeek-V3 does
+_MLA = dict(attn_type="mla", kv_lora_rank=16, rope_head_dim=8,
+            nope_head_dim=16, v_head_dim=16)
+
+
+@pytest.mark.parametrize("family", ["qwen", "sigmoid"])
+def test_use_pallas_keeps_the_training_path(monkeypatch, caplog, family):
+    """``use_pallas`` picks neither the attention core nor the one-device
+    MoE path: a tiny MoE's loss and gradients agree with it on and off,
+    and every MoE call takes ``grouped``.  What differs is the CE loss,
+    a vocabulary-streaming kernel (interpret mode here) against XLA's
+    whole-row softmax, both float32: hence a relative tolerance of 1e-5
+    on the loss and 1e-4 of the largest gradient entry per leaf."""
+    cfg = _tiny(family, **(_MLA if family == "sigmoid" else {}))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
+    monkeypatch.setattr(moe, "_PATH_LOGGED", set())
+    caplog.set_level(logging.INFO, logger=moe.__name__)
+
+    def loss_and_grads(c):
+        return jax.value_and_grad(lambda p: M.loss_fn(p, c, batch)[0])(params)
+
+    loss, grads = loss_and_grads(cfg)
+    loss_p, grads_p = loss_and_grads(cfg.replace(use_pallas=True))
+    np.testing.assert_allclose(float(loss_p), float(loss), rtol=1e-5)
+    for g, r in zip(jax.tree.leaves(grads_p), jax.tree.leaves(grads)):
+        assert float(jnp.max(jnp.abs(g - r))) <= (
+            1e-4 * float(jnp.max(jnp.abs(r))) + 1e-7)
+    paths = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("moe path: ")]
+    assert paths and all(m.startswith("moe path: grouped") for m in paths)
 
 _SCRIPT = r"""
 import os

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.kd_loss.ops import ce_from_hidden, ce_kl_from_hidden
 from repro.kernels.moe_dispatch.kernel import gather_scatter_add_rows
 from repro.kernels.moe_gemm.ops import grouped_ffn
@@ -64,20 +63,16 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
-def test_flash_attention_fwd(one_chip):
-    q = ((2, 512, H, DH), BF16)
-    fn = functools.partial(flash_attention, causal=True, interpret=False)
-    _compile(fn, one_chip, q, q, q)
-
-
-@pytest.mark.parametrize("B,S,Dq,Dv", [(2, 2048, DH, DH), (1, 8192, 192, 128)],
-                         ids=["qwen2moe.tune", "moonlight.tune"])
-def test_splash_attention_fwd_and_grad(one_chip, B, S, Dq, Dv):
+@pytest.mark.parametrize("B,S,NH,Dq,Dv", [
+    (2, 2048, H, DH, DH), (1, 8192, H, 192, 128), (4, 256, 12, 64, 64),
+], ids=["qwen2moe.tune", "moonlight.tune", "gpt2"])
+def test_splash_attention_fwd_and_grad(one_chip, B, S, NH, Dq, Dv):
     """The training attention core at both benchmark cells' shapes (the
-    MLA one with qk 192 / v 128): the forward kernel, then the backward's
-    dk/dv and dq kernels."""
+    MLA one with qk 192 / v 128) and at the GPT-2 device models' of
+    ``chip_smoke.py`` (12 x 64 heads, 4 x 256 tokens): the forward
+    kernel, then the backward's dk/dv and dq kernels."""
     fwd = functools.partial(layers.splash_attention, interpret=False)
-    q, v = ((B, S, H, Dq), BF16), ((B, S, H, Dv), BF16)
+    q, v = ((B, S, NH, Dq), BF16), ((B, S, NH, Dv), BF16)
     _compile(fwd, one_chip, q, q, v)
 
     def loss(q, k, v):
