@@ -8,6 +8,7 @@ layers are fine-tuned on server-side public data.  The freeze mask feeds
 """
 from __future__ import annotations
 
+import logging
 import re
 from typing import Dict
 
@@ -43,14 +44,46 @@ def trainable_fraction(params) -> float:
     return train / max(tot, 1)
 
 
+_TAP_LOGGED: set = set()
+
+
+def _log_taps(params, freeze_mask) -> None:
+    """Log, once per distinct count, how many frozen leaves are tapped
+    and the bytes of gradient the step no longer holds for them."""
+    leaves = [p for p, m in zip(jax.tree.leaves(params),
+                                jax.tree.leaves(freeze_mask)) if not m]
+    key = (len(leaves), sum(p.size * p.dtype.itemsize for p in leaves))
+    if key not in _TAP_LOGGED:
+        _TAP_LOGGED.add(key)
+        logging.getLogger(__name__).info(
+            "frozen taps: %d leaves, %d gradient bytes not materialised",
+            *key)
+
+
 def make_tune_step(cfg: ModelConfig, freeze_mask, *, weight_decay=0.01,
                    mesh=None):
+    """One Phase III step.  Only the trainable leaves are differentiated;
+    each frozen leaf enters the loss as ``M.Tapped(weight, probe)``, and
+    its probe's gradient is the squared norm of the weight's gradient,
+    summed per layer inside the layer scan.  The clip norm covers the
+    frozen leaves as if their gradients were held."""
     def step(params, opt_state, batch, lr):
-        (loss, metrics), grads = jax.value_and_grad(
-            lambda p: M.loss_fn(p, cfg, batch, mesh=mesh), has_aux=True)(params)
+        train = jax.tree.map(lambda p, m: p if m else None, params,
+                             freeze_mask)
+        probes = M.frozen_probes(params, freeze_mask)
+        _log_taps(params, freeze_mask)
+
+        def loss_of(train, probes):
+            p = jax.tree.map(lambda w, t, pr: t if pr is None
+                             else M.Tapped(w, pr), params, train, probes)
+            return M.loss_fn(M.untap(p), cfg, batch, mesh=mesh)
+
+        (loss, metrics), (grads, sq) = jax.value_and_grad(
+            loss_of, argnums=(0, 1), has_aux=True)(train, probes)
+        frozen_sq = sum(jnp.sum(s) for s in jax.tree.leaves(sq))
         params, opt_state, stats = adamw_update(
             grads, opt_state, params, lr=lr, weight_decay=weight_decay,
-            freeze_mask=freeze_mask)
+            freeze_mask=freeze_mask, frozen_sq=frozen_sq)
         metrics.update(stats)
         return params, opt_state, loss, metrics
 

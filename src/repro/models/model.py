@@ -18,7 +18,7 @@ Layouts:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,10 +71,69 @@ def _unroll(cfg: ModelConfig, xs) -> int:
     return jax.tree.leaves(xs)[0].shape[0] if cfg.scan_unroll else 1
 
 
+class Tapped(NamedTuple):
+    """A frozen parameter of a loss whose gradient is wanted only for its
+    squared norm: the weight ``w``, not differentiated, and a float32
+    ``probe`` that is.  A leaf of a stacked tree carries one probe entry
+    per layer group, ``(L,)``, and rides ``_scan``'s ``xs`` whole; it is
+    tapped once the scan has sliced it to a scalar probe."""
+    w: Any
+    probe: Any
+
+
+@jax.custom_vjp
+def tap(w, probe):
+    """``w`` unchanged.  The backward gives ``w`` no cotangent and
+    ``probe`` the float32 squared norm of ``w``'s: reduced where it is
+    formed, so no stacked gradient of a frozen leaf is ever written."""
+    return w
+
+
+def _tap_fwd(w, probe):
+    return w, None
+
+
+def _tap_bwd(_, dw):
+    return None, jnp.sum(jnp.square(dw.astype(jnp.float32)))
+
+
+tap.defvjp(_tap_fwd, _tap_bwd)
+
+
+def untap(tree):
+    """Apply ``tap`` to each ``Tapped`` node of ``tree`` whose probe is a
+    scalar; the rest, plain arrays included, are returned as they are."""
+    def one(x):
+        if isinstance(x, Tapped) and jnp.ndim(x.probe) == 0:
+            return tap(x.w, x.probe)
+        return x
+    return jax.tree.map(one, tree, is_leaf=lambda x: isinstance(x, Tapped))
+
+
+# the decoder's stacked layer trees, which hold the experts; ``_scan``
+# runs over their leading layer-group axis
+_STACKS = ("blocks", "dense_blocks")
+
+
+def frozen_probes(params, freeze_mask):
+    """Zero float32 probes for ``Tapped``: one entry per layer group for a
+    frozen leaf (mask False) of a ``_STACKS`` tree, a scalar for any other
+    frozen leaf (tapped whole where the loss starts), None for a
+    trainable leaf."""
+    def probe(path, p, trainable):
+        if trainable:
+            return None
+        stacked = getattr(path[0], "key", None) in _STACKS
+        return jnp.zeros(p.shape[:1] if stacked else (), jnp.float32)
+    return jax.tree_util.tree_map_with_path(probe, params, freeze_mask)
+
+
 @scopes.scoped(scopes.STACK)
 def _scan(cfg: ModelConfig, body, init, xs):
-    """A scan over stacked layers."""
-    return jax.lax.scan(body, init, xs, unroll=_unroll(cfg, xs))
+    """A scan over stacked layers; the ``Tapped`` leaves of each layer's
+    slice of ``xs`` are tapped before ``body`` sees them."""
+    return jax.lax.scan(lambda c, x: body(c, untap(x)), init, xs,
+                        unroll=_unroll(cfg, xs))
 
 
 def _maybe_remat(cfg: ModelConfig, fn):

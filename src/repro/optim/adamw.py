@@ -90,9 +90,15 @@ def adamw_init(params, *, freeze_mask=None, state_dtype=None, policy=None):
     return state
 
 
-def global_norm_clip(grads, max_norm: float):
+def global_norm_clip(grads, max_norm: float, frozen_sq=None):
+    """Scale ``grads`` to a global norm of at most ``max_norm``.
+    ``frozen_sq``: the float32 squared norm of gradients that count in
+    the norm but are not in ``grads`` (None leaves there), as the
+    frozen-leaf taps of ``models.model.tap`` give it."""
     leaves = [jnp.sum(jnp.square(g.astype(jnp.float32)))
               for g in jax.tree.leaves(grads)]
+    if frozen_sq is not None:
+        leaves.append(frozen_sq)
     gnorm = jnp.sqrt(sum(leaves))
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(gnorm, 1e-9))
     return jax.tree.map(lambda g: (g * scale).astype(g.dtype), grads), gnorm
@@ -102,8 +108,11 @@ def global_norm_clip(grads, max_norm: float):
 def adamw_update(grads, state, params, *, lr, b1: float = 0.9,
                  b2: float = 0.95, eps: float = 1e-8,
                  weight_decay: float = 0.0, freeze_mask=None,
-                 clip_norm: float = 1.0):
+                 clip_norm: float = 1.0, frozen_sq=None):
     """One AdamW step.  Returns (new_params, new_state, stats).
+
+    A frozen leaf's gradient may be None when ``frozen_sq`` carries its
+    squared norm into the clip (``global_norm_clip``).
 
     A state carrying ``"v_scale"`` (int8 second moments) is dequantized
     to fp32 before the update and re-quantized after — the update math
@@ -113,7 +122,7 @@ def adamw_update(grads, state, params, *, lr, b1: float = 0.9,
     v_quantized = "v_scale" in state
     step = state["step"] + 1
     if clip_norm:
-        grads, gnorm = global_norm_clip(grads, clip_norm)
+        grads, gnorm = global_norm_clip(grads, clip_norm, frozen_sq)
     else:
         gnorm = jnp.zeros((), jnp.float32)
     c1 = 1.0 - b1 ** step.astype(jnp.float32)

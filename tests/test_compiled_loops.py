@@ -7,6 +7,10 @@ batches, same lr schedule, same updates.  Also pins the comm-cost
 accounting fix: uploads are billed from the *configured* device model's
 parameter count (Eq. 5 / Fig. 8), not the in-memory reduced tree.
 """
+import collections
+import logging
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +25,7 @@ from repro.federated.device import (DeviceSpec, _device_init, _init_bucket,
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.optim import adamw_init, adamw_update, cosine_schedule
+from repro.utils import scopes
 from repro.utils.pytree import tree_bytes
 
 V = 64
@@ -175,6 +180,90 @@ def test_tune_epoch_matches_per_step(corpus):
     np.testing.assert_allclose(np.asarray(losses), np.array(ref_losses),
                                rtol=0, atol=5e-6)
     assert _tree_max_diff(got_p, ref_p) < 1e-5
+
+
+# the tapped tune epoch: 3 MoE layers, widths chosen so that no weight
+# of another leaf and no saved activation (T = 2 x 24 rows) has the
+# stacked shape of a frozen leaf
+TAP_CFG = MOE_CFG.replace(name="scan-moe-tap", n_layers=3, n_experts=8,
+                          moe_d_ff=40)
+_METADATA = re.compile(r",? ?metadata=\{[^}]*\}")
+_FIRST = re.compile(r"^(%|ENTRY)", re.M)   # tables of source files precede
+
+
+def _optimized_hlo(fn, *args):
+    # a fresh function each time, so that nothing traced before is reused
+    text = jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+    return _METADATA.sub("", text[_FIRST.search(text).start():])
+
+
+def _shape(text):
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+def test_tune_epoch_writes_no_frozen_gradient_stack(monkeypatch, caplog):
+    """A frozen leaf reaches the clip as a squared norm per layer, taken
+    inside the layer scan: no ``dynamic-update-slice`` writes a buffer of
+    a frozen leaf's stacked shape, and each ``while`` loop carries such a
+    buffer only as often as there are frozen weights of that shape (the
+    weights themselves, read by the forward and the backward)."""
+    from repro.federated import server
+    monkeypatch.setattr(tuning, "_TAP_LOGGED", set())
+    caplog.set_level(logging.INFO, logger=tuning.__name__)
+    params = M.init_params(jax.random.PRNGKey(0), TAP_CFG)
+    mask, opt = tuning.init_tuning(params)
+    frozen = [p for p, m in zip(jax.tree.leaves(params),
+                                jax.tree.leaves(mask)) if not m]
+    weights = collections.Counter(tuple(p.shape) for p in frozen)
+    assert len(frozen) == 6 and all(len(s) >= 3 for s in weights)
+    tok = jnp.zeros((2, 2, 24), jnp.int32)
+    text = server._tune_epoch_fn(TAP_CFG, None, mask, 2, 1e-3, 0).lower(
+        params, opt, {"tokens": tok, "labels": tok}).compile().as_text()
+
+    updates = re.findall(r"= \w+\[([\d,]*)\]\S* dynamic-update-slice\(",
+                         text)
+    assert updates and not {_shape(u) for u in updates} & set(weights)
+    loops = re.findall(r"= \((.*)\) while\(", text)
+    assert len(loops) >= 2
+    for carried in loops:
+        shapes = collections.Counter(
+            _shape(d) for d in re.findall(r"\w+\[([\d,]*)\]", carried))
+        assert all(shapes[s] <= n for s, n in weights.items()), shapes
+    assert [r.getMessage() for r in caplog.records] == [
+        f"frozen taps: 6 leaves, {sum(p.nbytes for p in frozen)} gradient "
+        "bytes not materialised"]
+
+
+def _parent_scan(cfg, body, init, xs):
+    """``model._scan`` as it was before frozen leaves were tapped."""
+    return jax.lax.scan(body, init, xs, unroll=M._unroll(cfg, xs))
+
+
+@pytest.mark.parametrize("program", ["distill_step", "prefill"])
+def test_untapped_programs_unchanged(monkeypatch, program):
+    """A scan whose layers carry no ``Tapped`` leaf compiles to the
+    program it compiled to before the taps: the Phase II distillation
+    step and a MoE prefill, optimized HLO without metadata."""
+    if program == "distill_step":
+        t_params = M.init_params(jax.random.PRNGKey(7), CFG_B)
+        trainable = {"student": M.init_params(jax.random.PRNGKey(8), CFG_A),
+                     "vaa": vaa_mod.init_vaa(
+                         jax.random.PRNGKey(9), n_stages=2,
+                         d_student=CFG_A.d_model, d_teacher=CFG_B.d_model,
+                         d=16, n_heads=2, p_q=8)}
+        tok = jnp.zeros((BATCH, SEQ), jnp.int32)
+        fn = distill.make_distill_step(
+            CFG_A, CFG_B, optimizer_update=adamw_update, alpha=1.0,
+            beta=1.0, temperature=2.0, n_stages=2, vaa_heads=2, p_q=8)
+        args = (trainable, adamw_init(trainable), t_params,
+                {"tokens": tok, "labels": tok}, 1e-3)
+    else:
+        params = M.init_params(jax.random.PRNGKey(0), TAP_CFG)
+        fn = lambda p, b: M.prefill(p, TAP_CFG, b)
+        args = (params, {"tokens": jnp.zeros((2, 24), jnp.int32)})
+    now = _optimized_hlo(fn, *args)
+    monkeypatch.setattr(M, "_scan", scopes.scoped(scopes.STACK)(_parent_scan))
+    assert now == _optimized_hlo(fn, *args)
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,7 @@ from repro.core import vaa as vaa_mod
 from repro.core.distill import select_stages
 from repro.models import model as M
 from repro.models.config import ModelConfig
-from repro.optim import adamw_init, adamw_update
+from repro.optim import adamw_init, adamw_update, global_norm_clip
 from repro.utils.pytree import tree_average
 
 SMALL = dict(vocab_size=128, dtype="float32", remat=False,
@@ -253,3 +253,69 @@ def test_frozen_experts_unchanged_by_tuning_step():
         - new_params["blocks"]["sub0"]["moe"]["router"]))) > 0
     # and frozen moments are scalar (memory claim of §IV.D)
     assert opt["m"]["blocks"]["sub0"]["moe"]["wi_gate"].shape == ()
+
+
+# the DeepSeek-V3 block at CPU size: MLA with a full-rank query, one
+# leading dense layer, the sigmoid router with its correction bias
+V3_TINY = dict(name="v3", n_layers=3, attn_type="mla", q_lora_rank=0,
+               kv_lora_rank=16, rope_head_dim=8, nope_head_dim=16,
+               v_head_dim=16, head_dim=24, n_experts=8, top_k=3,
+               n_shared_experts=2, moe_d_ff=32, first_dense_layers=1,
+               router_score="sigmoid", n_group=2, topk_group=1,
+               routed_scaling_factor=2.5)
+
+
+@pytest.mark.parametrize("family", ["qwen2_moe", "deepseek_v3"])
+def test_tune_step_matches_full_gradient_step(family):
+    """The tapped step, which differentiates only the trainable leaves
+    and takes each frozen leaf's squared gradient norm inside the layer
+    scan, against a step written here: ``jax.grad`` over every leaf,
+    then ``global_norm_clip`` over all of them.  The clip engages, so
+    the frozen share of the norm sets every trainable update."""
+    cfg = moe_cfg(n_layers=3, n_experts=4) if family == "qwen2_moe" \
+        else moe_cfg(**V3_TINY)
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    if family == "deepseek_v3":   # a correction bias that moves routing
+        params = jax.tree_util.tree_map_with_path(
+            lambda p, x: 0.1 * jax.random.normal(jax.random.PRNGKey(5),
+                                                 x.shape)
+            if "e_score_correction_bias" in str(p) else x, params)
+    mask, opt = tuning.init_tuning(params)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
+    lr, clip = 1e-2, 1.0
+
+    def full_step(params, opt):
+        (loss, _), g = jax.value_and_grad(
+            lambda p: M.loss_fn(p, cfg, batch), has_aux=True)(params)
+        train_norm = jnp.sqrt(sum(
+            jnp.sum(jnp.square(x)) for x, m in
+            zip(jax.tree.leaves(g), jax.tree.leaves(mask)) if m))
+        g, gnorm = global_norm_clip(g, clip)
+        new, opt, _ = adamw_update(g, opt, params, lr=lr,
+                                   weight_decay=0.01, freeze_mask=mask,
+                                   clip_norm=0.0)
+        return new, opt, loss, gnorm, train_norm
+
+    want_p, _, want_loss, want_norm, train_norm = jax.jit(full_step)(
+        params, opt)
+    got_p, got_o, loss, metrics = jax.jit(
+        tuning.make_tune_step(cfg, mask))(params, opt, batch, lr)
+
+    np.testing.assert_array_equal(np.asarray(loss), np.asarray(want_loss))
+    np.testing.assert_allclose(float(metrics["grad_norm"]),
+                               float(want_norm), rtol=1e-6)
+    assert float(want_norm) > clip                 # the clip engages
+    assert float(train_norm) < 0.999 * float(want_norm)  # frozen share
+    flat = zip(jax.tree.leaves(params), jax.tree.leaves(got_p),
+               jax.tree.leaves(want_p), jax.tree.leaves(mask),
+               jax.tree.leaves(got_o["m"]), jax.tree.leaves(got_o["v"]))
+    for before, got, want, trainable, m, v in flat:
+        if trainable:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=0, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(before))
+            assert m.shape == v.shape == ()
